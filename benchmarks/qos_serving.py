@@ -290,6 +290,10 @@ if __name__ == "__main__":
                     "and compile spans) and write it to this path")
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
     tracer = None
     if args.trace:
         tracer = obs_trace.Tracer()

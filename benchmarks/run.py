@@ -28,6 +28,8 @@ committed full-grid front the pruned sweep recovers (writes
 data plane sharded over that many devices (pair with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` on a 1-GPU/CPU
 host).
+A module that raises prints ``<name>,ERROR,<msg>``; the other modules
+still run, and the process then exits 1.
 ``--check-regression <baseline-dir-or-file>`` compares the artifacts
 produced THIS run against committed baselines (benchmarks/baselines/) and
 exits non-zero beyond the noise margin -- the CI perf gate. Structural
@@ -312,6 +314,10 @@ def main() -> None:
                             for k, v in sorted(support.items())
                             if k in {x.strip() for x in keys}))
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
     print("name,us_per_call,derived")
 
     def report(name: str, us, derived: str = ""):
@@ -325,6 +331,7 @@ def main() -> None:
 
     from repro.obs import metrics as obs_metrics
 
+    failed = []
     for key in keys:
         mod = MODULES[key.strip()]
         accepted = inspect.signature(mod.main).parameters
@@ -345,8 +352,9 @@ def main() -> None:
                     mod.main(report, **kw)
             else:
                 mod.main(report, **kw)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other modules, then fail
             report(key, "ERROR", str(e)[:200])
+            failed.append(key.strip())
         report(f"_{key}_total_s", f"{time.time() - t0:.1f}")
 
     if tracer is not None:
@@ -354,6 +362,11 @@ def main() -> None:
         obs_trace.disable()
         tracer.save(args.trace)
         report("trace", len(tracer), args.trace)
+
+    if failed:
+        print(f"benchmark modules FAILED: {','.join(failed)}",
+              file=sys.stderr)
+        sys.exit(1)
 
     if args.check_regression:
         # after the module loop, OUTSIDE the per-module exception guard:

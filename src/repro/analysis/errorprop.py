@@ -40,12 +40,7 @@ import dataclasses
 import math
 from typing import Dict, List, Sequence
 
-from jax import core as jcore
-
-try:  # jax >= 0.4.x moved Literal around; import defensively
-    Literal = jcore.Literal
-except AttributeError:  # pragma: no cover
-    from jax._src.core import Literal  # type: ignore
+from jax.extend.core import Literal
 
 # Headroom constants (model assumptions, documented in docs/analysis.md).
 CANCEL_AMP = 4.0    # additive cancellation headroom (add/sub/dot/reduce)
@@ -94,7 +89,7 @@ class LoopReport:
     """One scan/while whose carry the injected error reaches."""
 
     kind: str        # "scan" | "while"
-    path: str        # subjaxpr path, e.g. "pjit/while.body"
+    path: str        # subjaxpr path, e.g. "jit/while.body"
     gain: float      # per-iteration amplification of the carry error
     diverges: bool   # while-loop carry with gain > 1: statically unbounded
     eqn_repr: str
@@ -226,7 +221,7 @@ def _walk(jaxpr, rel: Dict, path: str, loops: List[LoopReport]
             _bind_out(eqn, outs, rel)
             continue
 
-        if name in ("pjit", "closed_call", "core_call", "xla_call",
+        if name in ("jit", "closed_call", "core_call", "xla_call",
                     "custom_jvp_call", "custom_vjp_call", "remat", "remat2",
                     "checkpoint", "custom_vjp_call_jaxpr"):
             closed = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")

@@ -45,8 +45,10 @@ class MachineProfile:
 
 
 MACHINES: Dict[str, MachineProfile] = {
-    # TPU v5e-class chip (constants from the brief): the target substrate
-    # for roofline analysis and the default for cost prediction.
+    # One TPU v5e chip, from Google Cloud's "TPU v5e" page: 197 TFLOP/s
+    # bf16, 819 GB/s HBM, 1,600 Gbit/s of interconnect over 4 links. The
+    # target substrate for roofline analysis and the default for cost
+    # prediction.
     "tpu-v5e": MachineProfile(name="tpu-v5e", peak_flops=197e12,
                               hbm_bw=819e9, ici_bw=50e9,
                               dispatch_s=2e-6),
@@ -60,6 +62,13 @@ MACHINES: Dict[str, MachineProfile] = {
 }
 
 DEFAULT_MACHINE = "tpu-v5e"
+
+# `jax.Device.device_kind` -> profile name. A device that is not listed
+# has no profile: `device_machine` raises rather than guess one.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+    "cpu": "host-sim",
+}
 
 # substrate name (repro.core.substrate) -> machine profile name
 SUBSTRATE_MACHINES: Dict[str, str] = {
@@ -116,8 +125,7 @@ def measure_machine(name: str = MEASURED_MACHINE, *, size: int = 384,
     dispatch_s = max(_med(jax.jit(lambda x: x + 1.0), jnp.float32(0.0)),
                      1e-7)
 
-    base_name = ("tpu-v5e" if jax.default_backend() == "tpu"
-                 else "host-sim")
+    base_name = device_machine()
     profile = MachineProfile(name=name, peak_flops=peak_flops,
                              hbm_bw=hbm_bw,
                              ici_bw=MACHINES[base_name].ici_bw,
@@ -125,6 +133,20 @@ def measure_machine(name: str = MEASURED_MACHINE, *, size: int = 384,
     if register:
         MACHINES[name] = profile
     return profile
+
+
+def device_machine(device=None) -> str:
+    """The profile name of `device` (default: the first JAX device), from
+    its `device_kind`. An unlisted kind is a KeyError."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    kind = device.device_kind
+    if kind not in DEVICE_KINDS:
+        raise KeyError(
+            f"no machine profile for device_kind {kind!r} (platform "
+            f"{device.platform!r}); known kinds: {sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[kind]
 
 
 def get_machine(machine: Union[str, MachineProfile, None] = None

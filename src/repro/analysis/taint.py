@@ -17,7 +17,7 @@ unsafe-to-perturb control flow) is the classic AC safety condition, and it
 is checkable purely on the jaxpr.
 
 The walk is conservative: any tainted input taints every output of an eqn
-unless the primitive is handled structurally (pjit / cond / while / scan
+unless the primitive is handled structurally (jit / cond / while / scan
 recurse into their subjaxprs; while/scan carries run to a fixpoint).
 Detector STATE (e.g. TAF's `remaining` counter) steering a `cond` is the
 approximation *mechanism*, not a defect -- callers control that by choosing
@@ -28,12 +28,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Sequence, Set
 
-from jax import core as jcore
-
-try:  # jax >= 0.4.x moved Literal around; import defensively
-    Literal = jcore.Literal
-except AttributeError:  # pragma: no cover
-    from jax._src.core import Literal  # type: ignore
+from jax.extend.core import Literal
 
 # sink primitive -> (operand slice holding indices, sink kind)
 _INDEX_SINKS = {
@@ -54,7 +49,7 @@ _INDEX_SINKS = {
 class TaintSink:
     primitive: str
     kind: str        # "branch predicate" | "while predicate" | "... indices"
-    path: str        # subjaxpr path, e.g. "pjit/cond[1]"
+    path: str        # subjaxpr path, e.g. "jit/cond[1]"
     eqn_repr: str
 
     def to_json(self) -> Dict:
@@ -100,7 +95,7 @@ def _walk(jaxpr, tainted: Set, path: str, sinks: List[TaintSink]) -> Set:
                 tainted.add(eqn.outvars[oi])
             continue
 
-        if name in ("pjit", "closed_call", "core_call", "xla_call",
+        if name in ("jit", "closed_call", "core_call", "xla_call",
                     "custom_jvp_call", "custom_vjp_call", "remat", "remat2",
                     "checkpoint", "custom_vjp_call_jaxpr"):
             closed = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")

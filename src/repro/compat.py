@@ -1,21 +1,14 @@
-"""Version-compatibility shims over the moving parts of the jax API.
+"""Thin helpers over the jax API constructs the repo spells in one way.
 
-The repo pins no exact jax version; it must run on the 0.4.x series (the
-container ships 0.4.37) and on >= 0.5, which renamed or relocated several
-distributed-runtime entry points. Every cross-version construct lives HERE,
-in one helper per construct, so call sites never branch on the jax version
-themselves:
+The repo runs on jax 0.9.0. Each construct that call sites would otherwise
+repeat lives here, in one helper:
 
-* ``jax.sharding.AxisType`` (>= 0.5): explicit-sharding axis types. On
-  0.4.x meshes are implicitly fully "auto", so omitting the argument is the
-  exact equivalent.
-* ``jax.shard_map`` (>= 0.6 top-level export; 0.4.x home is
-  ``jax.experimental.shard_map``) and its replication-check kwarg
-  (``check_vma``, formerly ``check_rep``).
-
-The AC surveys (Leon et al.) call out exactly this kind of cross-version
-fragility as a practical barrier to adopting approximation systems; keeping
-the portability surface in one module is the mitigation.
+* ``make_mesh`` -- `jax.make_mesh` with every axis typed
+  ``jax.sharding.AxisType.Auto``;
+* ``cost_analysis`` -- a compiled program's cost dict (empty when the
+  backend reports none);
+* ``shard_map`` -- `jax.shard_map` with its replication check
+  (``check_vma``) named ``check_replication``.
 """
 from __future__ import annotations
 
@@ -23,50 +16,25 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-try:  # newer jax exports shard_map at the top level
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KWARG = "check_vma"
-except ImportError:  # jax 0.4.x: experimental home, `check_rep` kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KWARG = "check_rep"
-
 
 def make_mesh(shape: Tuple[int, ...], axis_names: Sequence[str], *,
               devices: Optional[Sequence] = None):
-    """`jax.make_mesh` with auto axis types on every jax version.
-
-    On jax >= 0.5 this passes ``axis_types=(AxisType.Auto, ...)`` explicitly;
-    on 0.4.x (no ``AxisType``) the argument is omitted, which means the same
-    thing.
-    """
+    """`jax.make_mesh` with auto axis types."""
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if _AXIS_TYPE is not None:
-        kwargs["axis_types"] = (_AXIS_TYPE.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(shape), tuple(axis_names), **kwargs)
+    return jax.make_mesh(
+        tuple(shape), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        **kwargs)
 
 
 def cost_analysis(compiled) -> dict:
-    """Flat cost dict of a compiled computation on every jax version.
-
-    jax 0.4.x returns a one-element list of per-computation dicts (or None);
-    >= 0.5 returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """Flat cost dict of a compiled computation ({} when unreported)."""
+    return compiled.cost_analysis() or {}
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_replication: bool = True):
-    """`shard_map` with the replication check spelled portably.
-
-    ``check_replication`` maps to ``check_vma`` (jax >= 0.6) or ``check_rep``
-    (0.4.x) -- same semantics, renamed kwarg.
-    """
-    kwargs = {_SHARD_MAP_CHECK_KWARG: check_replication}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    """`jax.shard_map`; ``check_replication`` is its ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)

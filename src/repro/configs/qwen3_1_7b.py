@@ -1,6 +1,8 @@
-"""qwen3-1.7b [dense]: qk_norm, GQA kv=8, head_dim 128.
+"""qwen3-1.7b [dense]: qk_norm, GQA kv=8, head_dim 128, tied embeddings.
 
-28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936 [hf:Qwen/Qwen3-8B; hf].
+28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936, bf16 weights
+[hf:Qwen/Qwen3-1.7B config.json: tie_word_embeddings=true,
+torch_dtype=bfloat16, rope_theta=1e6, rms_norm_eps=1e-6].
 """
 from repro.configs.base import ModelConfig
 
@@ -17,6 +19,8 @@ CONFIG = ModelConfig(
     qk_norm=True,
     rope_theta=1e6,
     norm_eps=1e-6,
+    tie_embeddings=True,
+    param_dtype="bfloat16",
 )
 
 

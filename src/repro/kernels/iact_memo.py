@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import tuning
+
 _BIG = 3.4e38  # python float: jnp constants would be captured by the kernel
 
 
@@ -51,6 +53,7 @@ def _iact_kernel(thresh_ref, x_ref, w1_ref, w2_ref, o_ref, mask_ref,
         meta_ref[1] = 0  # number of valid entries
         keys_ref[...] = jnp.zeros_like(keys_ref)
         vals_ref[...] = jnp.zeros_like(vals_ref)
+        mask_ref[...] = jnp.zeros_like(mask_ref)
 
     x = x_ref[...].astype(jnp.float32)                       # (R, d_in)
     keys = keys_ref[...]                                     # (T, d_in)
@@ -65,6 +68,9 @@ def _iact_kernel(thresh_ref, x_ref, w1_ref, w2_ref, o_ref, mask_ref,
     hit = jnp.logical_and(min_d2 < threshold * threshold, n_valid > 0)
     n_rows = x.shape[0]
     approximate = jnp.sum(hit.astype(jnp.int32)) * 2 > n_rows  # majority
+    step = jax.lax.broadcasted_iota(jnp.int32, mask_ref.shape, 1)
+    mask_ref[...] = jnp.where(step == b, approximate.astype(jnp.int32),
+                              mask_ref[...])
 
     @pl.when(approximate)
     def _approx_path():
@@ -74,7 +80,6 @@ def _iact_kernel(thresh_ref, x_ref, w1_ref, w2_ref, o_ref, mask_ref,
         out = jnp.dot(onehot.astype(jnp.float32), vals_ref[...],
                       preferred_element_type=jnp.float32)
         o_ref[...] = out.astype(o_ref.dtype)
-        mask_ref[0] = 1
 
     @pl.when(jnp.logical_not(approximate))
     def _accurate_path():
@@ -84,7 +89,6 @@ def _iact_kernel(thresh_ref, x_ref, w1_ref, w2_ref, o_ref, mask_ref,
         y = jnp.dot(h, w2_ref[...].astype(jnp.float32),
                     preferred_element_type=jnp.float32)
         o_ref[...] = y.astype(o_ref.dtype)
-        mask_ref[0] = 0
         # write phase -- single writer: farthest row from any cached value
         score = jnp.where(min_d2 >= _BIG, _BIG, min_d2)
         writer = jnp.argmax(score)
@@ -124,6 +128,18 @@ def iact_rowfn(x: jnp.ndarray, w1: jnp.ndarray, w2: jnp.ndarray, *,
             "kernels.tuning.search_space() enumerates only divisor-valid "
             "shapes for these operands.")
     num_b = n // block_rows
+    need = tuning.vmem_bytes(
+        "iact_rowfn", (x.shape, w1.shape, w2.shape),
+        {"block_rows": block_rows}, itemsize=jnp.dtype(w1.dtype).itemsize,
+        table_size=table_size)
+    if need > tuning.VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"iact_rowfn needs ~{need / 2 ** 20:.1f} MiB of VMEM for "
+            f"d_in={d_in}, d_h={d_h}, d_out={d_out}, block_rows="
+            f"{block_rows} in {jnp.dtype(w1.dtype).name}, over the "
+            f"{tuning.VMEM_BUDGET_BYTES // 2 ** 20} MiB limit "
+            "(kernels.tuning.VMEM_BUDGET_BYTES): both weights are held "
+            "whole in VMEM, so narrow d_h or use bf16 weights.")
 
     thresh = jnp.asarray(threshold, jnp.float32).reshape((1,))
     kernel = functools.partial(_iact_kernel, table_size=table_size)
@@ -137,7 +153,9 @@ def iact_rowfn(x: jnp.ndarray, w1: jnp.ndarray, w2: jnp.ndarray, *,
         ],
         out_specs=[
             pl.BlockSpec((block_rows, d_out), lambda b, thresh_ref: (b, 0)),
-            pl.BlockSpec((1,), lambda b, thresh_ref: (b,)),
+            # every block's flag in one resident block, each step setting
+            # its own entry: a (1,) block breaks Mosaic's tiling rule
+            pl.BlockSpec((1, num_b), lambda b, thresh_ref: (0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((table_size, d_in), jnp.float32),
@@ -150,8 +168,9 @@ def iact_rowfn(x: jnp.ndarray, w1: jnp.ndarray, w2: jnp.ndarray, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((n, d_out), out_dtype),
-            jax.ShapeDtypeStruct((num_b,), jnp.int32),
+            jax.ShapeDtypeStruct((1, num_b), jnp.int32),
         ],
+        compiler_params=tuning.compiler_params(None),
         interpret=interpret,
     )(thresh, x, w1, w2)
-    return y, mask.astype(bool)
+    return y, mask[0].astype(bool)

@@ -45,6 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.perforation import (FRACTION_KINDS, kept_indices,
                                     traced_execute_mask)
 from repro.core.types import PerforationParams
+from . import tuning
 
 _NEG = -1e30  # python float: jnp constants would be captured by the kernel
 
@@ -195,17 +196,14 @@ def perforated_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
     )
-    extra = {}
-    if pipeline:
-        # b, h, iq tile independent outputs; only kk carries the
-        # online-softmax scratch. Interpret mode ignores compiler_params.
-        extra["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
+    # b, h, iq tile independent outputs; only kk carries the online-softmax
+    # scratch. Interpret mode ignores compiler_params entirely.
+    semantics = (("parallel", "parallel", "parallel", "arbitrary")
+                 if pipeline else None)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        compiler_params=tuning.compiler_params(semantics),
         interpret=interpret,
-        **extra,
     )(kept_arr, live_arr, q, k, v)

@@ -38,6 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.perforation import (FRACTION_KINDS, kept_indices,
                                     traced_execute_mask)
 from repro.core.types import PerforationParams
+from . import tuning
 
 
 def _perf_matmul_kernel(kept_ref, live_ref, factor_ref, x_ref, w_ref, o_ref,
@@ -136,16 +137,13 @@ def perforated_matmul(x: jnp.ndarray, w: jnp.ndarray, *, block_m: int = 128,
                                (i, j)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
     )
-    extra = {}
-    if pipeline:
-        # i and j tile independent outputs; only kk carries the accumulator
-        # scratch. Interpret mode ignores compiler_params entirely.
-        extra["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    # i and j tile independent outputs; only kk carries the accumulator
+    # scratch. Interpret mode ignores compiler_params entirely.
+    semantics = ("parallel", "parallel", "arbitrary") if pipeline else None
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        compiler_params=tuning.compiler_params(semantics),
         interpret=interpret,
-        **extra,
     )(kept_arr, live_arr, factor_arr, x, w)

@@ -8,9 +8,16 @@ temporal sequence (Figure 4d), and VMEM/SMEM scratch is the paper's
 never on the total number of logical iterations.
 
 State (per column block; reset when i wraps to 0, i.e. kernel-lifetime scope):
-  window    VMEM (1, history_size) -- last accurate block-mean outputs
-  counters  SMEM (2,)              -- [filled, remaining]
+  window    VMEM (1, history_size) -- last accurate block-mean outputs, a
+                                      ring buffer (mean and deviation do not
+                                      depend on the order of its entries)
+  counters  SMEM (3,)              -- [filled, remaining, ring cursor]
   memo      VMEM (block_m, block_n) -- last accurate block output
+
+The approximation mask is stored column-block-major, (num_j, 1, num_i): each
+column block's row of flags is one block that stays resident while i runs,
+and a step sets its own entry with a select against an iota. A (1, 1) block
+per step would break Mosaic's (8, 128) tiling rule.
 
 The decision is **block-level** (paper `level(team)`): a scalar predicate
 drives ``@pl.when``, so an approximated tile genuinely skips its MXU dot --
@@ -33,29 +40,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import tuning
+
 
 def _taf_matmul_kernel(thresh_ref, x_ref, w_ref, o_ref, mask_ref,
                        window_ref, counters_ref, memo_ref, *,
                        history_size: int, prediction_size: int):
-    j = pl.program_id(0)  # column block (slow axis)
     i = pl.program_id(1)  # row block (fast axis) -- the temporal sequence
-    del j
     rsd_threshold = thresh_ref[0]
 
     @pl.when(i == 0)
     def _reset():  # kernel-lifetime state scope, fresh per column block
         counters_ref[0] = 0  # filled
         counters_ref[1] = 0  # remaining
+        counters_ref[2] = 0  # ring cursor into the window
         window_ref[...] = jnp.zeros_like(window_ref)
+        mask_ref[...] = jnp.zeros_like(mask_ref)
 
     remaining = counters_ref[1]
     approximate = remaining > 0
+    step = jax.lax.broadcasted_iota(jnp.int32, mask_ref.shape, 2)
+    mask_ref[...] = jnp.where(step == i, approximate.astype(jnp.int32),
+                              mask_ref[...])
 
     @pl.when(approximate)
     def _approx_path():
         # Return the last accurately-computed output; no MXU work at all.
         o_ref[...] = memo_ref[...].astype(o_ref.dtype)
-        mask_ref[0, 0] = 1
         counters_ref[1] = remaining - 1
 
     @pl.when(jnp.logical_not(approximate))
@@ -64,13 +75,15 @@ def _taf_matmul_kernel(thresh_ref, x_ref, w_ref, o_ref, mask_ref,
                     w_ref[...].astype(jnp.float32),
                     preferred_element_type=jnp.float32)
         o_ref[...] = y.astype(o_ref.dtype)
-        mask_ref[0, 0] = 0
         memo_ref[...] = y
-        # Slide the RSD window (hSize is tiny: 1..5).
+        # Overwrite the oldest window entry (hSize is tiny: 1..5). A select
+        # against an iota, not a scatter: Mosaic lowers no scatter.
         s = jnp.mean(y)
-        win = window_ref[0, :]
-        win = jnp.roll(win, -1).at[history_size - 1].set(s)
-        window_ref[0, :] = win
+        cursor = counters_ref[2]
+        slot = jax.lax.broadcasted_iota(jnp.int32, window_ref.shape, 1)
+        win = jnp.where(slot == cursor, s, window_ref[...])
+        window_ref[...] = win
+        counters_ref[2] = jax.lax.rem(cursor + 1, history_size)
         filled = jnp.minimum(counters_ref[0] + 1, history_size)
         counters_ref[0] = filled
         mu = jnp.mean(win)
@@ -128,29 +141,26 @@ def taf_matmul(x: jnp.ndarray, w: jnp.ndarray, *, block_m: int = 128,
         ],
         out_specs=[
             pl.BlockSpec((block_m, block_n), lambda j, i, thresh_ref: (i, j)),
-            pl.BlockSpec((1, 1), lambda j, i, thresh_ref: (i, j)),
+            pl.BlockSpec((1, 1, num_i), lambda j, i, thresh_ref: (j, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, history_size), jnp.float32),
-            pltpu.SMEM((2,), jnp.int32),
+            pltpu.SMEM((3,), jnp.int32),
             pltpu.VMEM((block_m, block_n), jnp.float32),
         ],
     )
-    extra = {}
-    if pipeline:
-        # j carries no state across grid steps (scratch resets at i == 0 per
-        # column block); i is the paper's temporal sequence and must stay
-        # sequential. Interpret mode ignores compiler_params entirely.
-        extra["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+    # j carries no state across grid steps (scratch resets at i == 0 per
+    # column block); i is the paper's temporal sequence and must stay
+    # sequential. Interpret mode ignores compiler_params entirely.
+    semantics = ("parallel", "arbitrary") if pipeline else None
     y, mask = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((m, n), out_dtype),
-            jax.ShapeDtypeStruct((num_i, num_j), jnp.int32),
+            jax.ShapeDtypeStruct((num_j, 1, num_i), jnp.int32),
         ],
+        compiler_params=tuning.compiler_params(semantics),
         interpret=interpret,
-        **extra,
     )(thresh, x, w)
-    return y, mask.astype(bool)
+    return y, mask[:, 0, :].T.astype(bool)

@@ -57,10 +57,20 @@ KERNELS = ("taf_matmul", "iact_rowfn", "perforated_matmul",
 # small enough to enumerate exhaustively before the cost-model prune.
 _POW2 = (8, 16, 32, 64, 128, 256, 512)
 
-# VMEM working-set budget per grid step (operand blocks + scratch). Real
-# v5e VMEM is ~128 MiB; stay well under so double-buffered operand blocks
-# (2x the in-specs) still fit.
-VMEM_BUDGET_BYTES = 48 * 2 ** 20
+# Scoped VMEM every kernel asks Mosaic for (`CompilerParams.
+# vmem_limit_bytes`), and the bound the search space and the kernel
+# wrappers hold `vmem_bytes` to. Without it Mosaic applies its own default
+# scope (16 MiB on v5e), well under the chip's 128 MiB of VMEM.
+VMEM_BUDGET_BYTES = 96 * 2 ** 20
+
+
+def compiler_params(dimension_semantics=None):
+    """The Mosaic compile options every kernel passes: its grid axes'
+    semantics (None = all sequential) and the scoped VMEM limit."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_BUDGET_BYTES)
+
 
 # Hardcoded fallbacks: the pre-tuning defaults of kernels/ops.py. Used when
 # no cache entry matches the operand shapes.
@@ -192,26 +202,38 @@ def grid_steps(kernel: str, shapes: Sequence[Sequence[int]],
 
 
 def vmem_bytes(kernel: str, shapes: Sequence[Sequence[int]],
-               config: Dict[str, int]) -> int:
-    """f32 working set of one grid step: operand/output blocks + scratch."""
+               config: Dict[str, int], itemsize: int = 4,
+               table_size: int = 4) -> int:
+    """VMEM one kernel call holds at `config`: two buffers of every block
+    that moves with the grid, one of a block that never moves (iACT's
+    weights), scratch, and the body's f32 temporaries. Operands are
+    `itemsize` bytes wide; outputs and temporaries are f32. For iact_rowfn
+    at d_in = d_out = 2048 and 128 rows, compiles for a described v5e
+    under the 96 MiB budget fit with d_h up to 10240 in bf16 and 5120 in
+    f32, and ran out of VMEM at 11264 and 6144: this estimate puts the
+    bound between each pair."""
     f = 4
     if kernel == "taf_matmul":
         k = shapes[0][1]
         bm, bn = config["block_m"], config["block_n"]
-        return f * (bm * k + k * bn + 2 * bm * bn + 8)
+        return (2 * itemsize * (bm * k + k * bn) + 4 * f * bm * bn
+                + f * (bm + 128))
     if kernel == "iact_rowfn":
         d_in, d_h = shapes[1]
         d_out = shapes[2][1]
         br = config["block_rows"]
-        table = 4 * (d_in + d_out)  # default table_size
-        return f * (br * d_in + d_in * d_h + d_h * d_out + br * d_out + table)
+        return (itemsize * (d_in * d_h + d_h * d_out)
+                + 2 * (itemsize * br * d_in + f * br * d_out)
+                + f * (br * d_h + br * table_size * d_in
+                       + table_size * (d_in + d_out)))
     if kernel == "perforated_matmul":
         bm, bn, bk = config["block_m"], config["block_n"], config["block_k"]
-        return f * (bm * bk + bk * bn + 2 * bm * bn)
+        return 2 * itemsize * (bm * bk + bk * bn) + 3 * f * bm * bn
     if kernel == "perforated_attention":
         d = shapes[0][3]
         bq, bkv = config["block_q"], config["block_kv"]
-        return f * (bq * d + 2 * bkv * d + 2 * bq * d + 2 * bq)
+        return (2 * itemsize * (2 * bq * d + 2 * bkv * d)
+                + f * (bq * d + 2 * bq + bq * bkv))
     raise ValueError(f"unknown kernel {kernel!r}")
 
 
@@ -282,12 +304,12 @@ def current_substrate() -> str:
 
 
 def current_machine_name() -> str:
-    """The registered roofline profile of the running substrate (tuning
-    caches key on registered names so committed caches lint cleanly --
-    the session-local "measured" profile sharpens predictions but is not a
-    stable cache key across machines)."""
-    from . import ops
-    return "tpu-v5e" if ops.ON_TPU else "host-sim"
+    """The registered roofline profile of the running device, from its
+    `device_kind` (tuning caches key on registered names so committed
+    caches lint cleanly -- the process-local "measured" profile sharpens
+    predictions but is not a stable cache key across machines)."""
+    from repro.analysis.machine import device_machine
+    return device_machine()
 
 
 def operand_shapes(arrays: Sequence) -> Tuple[Tuple[int, ...], ...]:

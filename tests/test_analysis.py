@@ -194,7 +194,7 @@ def test_taint_walks_into_pjit():
     closed = jax.make_jaxpr(step)(jnp.ones(4), jnp.ones(4))
     sinks = find_taint_sinks(closed, tainted_inputs=[0])
     assert any(s.kind == "branch predicate" for s in sinks)
-    assert all("pjit" in s.path for s in sinks)
+    assert all("jit" in s.path for s in sinks)
 
 
 # ------------------------------------------------------ A004: ladders

@@ -96,6 +96,19 @@ class TestWrapperErrors:
         with pytest.raises(ValueError, match="does not divide"):
             ops.perforated_matmul(x, w, block_m=48, block_n=32, block_k=32)
 
+    def test_iact_over_vmem_budget(self):
+        """Both iACT weights sit whole in VMEM: at the served FFN widths in
+        float32 (96 MiB of weights) the wrapper refuses, naming the
+        budget, before Mosaic would run out of VMEM. bf16 fits."""
+        s = lambda *shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt)  # noqa
+        call = lambda x, w1, w2: ops.iact_rowfn(x, w1, w2, block_rows=128)  # noqa
+        with pytest.raises(ValueError, match="VMEM_BUDGET_BYTES"):
+            jax.eval_shape(call, s(2048, 2048), s(2048, 6144),
+                           s(6144, 2048))
+        bf = jnp.bfloat16
+        jax.eval_shape(call, s(2048, 2048, dt=bf), s(2048, 6144, dt=bf),
+                       s(6144, 2048, dt=bf))
+
     def test_attention_block_mismatch(self):
         q, k, v = _arrays("perforated_attention")
         with pytest.raises(ValueError, match="does not divide"):
