@@ -4,7 +4,7 @@ One import surface for instrumented code::
 
     from repro.obs import trace, metrics, timing, recorder
 
-    with trace.span("tick", index=i):        # Perfetto "X" span
+    with trace.span("tick", index=i):        # profiler annotation + "X" span
         ...
     trace.event("knob_move", value=0.2)      # instant event
     obs.count("engine.recompiles")           # counter in BOTH sinks
@@ -14,9 +14,13 @@ One import surface for instrumented code::
 Contracts (enforced by tests/test_obs.py and benchmarks/obs_overhead.py,
 documented in docs/observability.md):
 
-  * zero-cost when disabled -- with no tracer installed, span()/event()/
-    counter() are a single module-attribute read; the serving hot path
-    shows zero extra compiles and >= 0.95 tick-throughput ratio;
+  * one clock with the device -- every span() is also a
+    `jax.profiler.TraceAnnotation`, recorded whenever a profiler session
+    is on; event() and counter() go to the Chrome buffer only;
+  * zero-cost when disabled -- with no tracer installed, span() is one
+    inactive `TraceAnnotation` and event()/counter() a single
+    module-attribute read; the serving hot path shows zero extra
+    compiles and >= 0.95 tick-throughput ratio;
   * never force device->host -- payloads are stored as given; lint rule
     A008 audits for traced values leaking into event payloads.
 """

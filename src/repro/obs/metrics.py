@@ -2,10 +2,10 @@
 
 The registry is process-ambient and ALWAYS ON for coarse call sites (one
 increment per sweep, per autotune rung, per engine construction): host-side
-tallies whose cost is a dict lookup. Hot-path instrumentation (the serving
-tick's per-tick histograms) is additionally gated on `trace.enabled()` so
-the disabled serving path stays zero-cost -- see docs/observability.md for
-the contract and `benchmarks/obs_overhead.py` for the gate.
+tallies whose cost is a dict lookup. The serving tick writes nothing here:
+its time is the `engine.tick` span's, on the profiler's clock -- see
+docs/observability.md for the contract and `benchmarks/obs_overhead.py`
+for the gate.
 
 `snapshot()` renders everything into ONE schema:
 

@@ -1,7 +1,15 @@
-"""Span-based event tracing with Chrome/Perfetto `trace_event` export.
+"""Span-based event tracing: profiler annotations plus Chrome/Perfetto
+`trace_event` export.
 
-One module-global `Tracer` (installed with `enable()` / scoped with
-`use()`) buffers three record kinds, all in the Chrome Trace Event
+Every `span(name)` enters a `jax.profiler.TraceAnnotation(name)`, so when
+a profiler session records (`jax.profiler.start_trace`, or the
+benchmark's `--trace 1`) the span lands on the profiler's own host line,
+on the same clock as the device's operations: open the `.xplane.pb` in
+Perfetto, or reduce it with `bench/trace/phases.py`. A profiler session
+is the only switch; there is no flag.
+
+On top of that, one module-global `Tracer` (installed with `enable()` /
+scoped with `use()`) buffers three record kinds in the Chrome Trace Event
 format (https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU)
 so a saved file opens directly in `ui.perfetto.dev` or `chrome://tracing`:
 
@@ -12,16 +20,23 @@ so a saved file opens directly in `ui.perfetto.dev` or `chrome://tracing`:
   counter(name, value)   -- a cumulative counter ("C" events): cache hits,
                             recompiles, canary ticks.
 
+Events and counters go to that buffer only. The buffer keeps its own
+`perf_counter` epoch; only the profiler's copy of a span lines up with
+the device.
+
 **Zero-cost-when-disabled contract.** With no tracer installed (the
-default), `span()` returns a shared no-op context manager and `event()`/
-`counter()` return immediately after one module-attribute read -- no
-allocation beyond the kwargs dict, no locking, no time syscalls. Nothing
-here may ever force a device->host transfer: payloads are stored AS GIVEN
-(never `np.asarray`'d), which is also what lets lint rule A008 detect a
-traced value leaking into an event payload (`docs/analysis.md`). The
-serving tick's instrumentation rides this contract -- see the
-`_cache_size()` + throughput-ratio regression gates in `tests/test_obs.py`
-and `benchmarks/obs_overhead.py`.
+default) and no profiler session, `span()` costs one inactive
+`TraceAnnotation` (its enter and exit check whether the profiler
+records), and `event()`/`counter()` return immediately after one
+module-attribute read -- no allocation beyond the kwargs dict, no
+locking, no time syscalls. The annotation takes the span's name only:
+its payload would rename the profiler event. Nothing here may ever force
+a device->host transfer: payloads are stored AS GIVEN (never
+`np.asarray`'d), which is also what lets lint rule A008 detect a traced
+value leaking into an event payload (`docs/analysis.md`). The serving
+tick's instrumentation rides this contract -- see the `_cache_size()` +
+throughput-ratio regression gates in `tests/test_obs.py` and
+`benchmarks/obs_overhead.py`.
 
 Buffering is thread-safe (one lock around the append; `tid` records the
 emitting thread) so the harness's thread-pool sweeps trace correctly.
@@ -35,44 +50,35 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # The single active tracer. Read (not locked) on every span()/event()/
 # counter() call -- module attribute reads are atomic in CPython, and the
 # only mutation is install/uninstall.
 _TRACER: Optional["Tracer"] = None
 
 
-class _NullSpan:
-    """Shared no-op context manager returned by `span()` when disabled."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    """A live timed region: records one "X" complete event on exit."""
+    """A live timed region: a profiler annotation that also records one
+    "X" complete event in the tracer's buffer on exit."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self._tracer._complete(self.name, self._t0, time.perf_counter(),
                                self.args)
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -208,11 +214,13 @@ def use(tracer: Optional[Tracer] = None):
         _TRACER = prev
 
 
-def span(name: str, **args) -> "_Span":
-    """Timed region context manager; a shared no-op when disabled."""
+def span(name: str, **args):
+    """Timed region context manager: a profiler annotation named `name`,
+    which also buffers a Chrome event (with `args`) when a tracer is
+    installed."""
     t = _TRACER
     if t is None:
-        return _NULL_SPAN
+        return TraceAnnotation(name)
     return _Span(t, name, args)
 
 

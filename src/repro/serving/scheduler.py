@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from repro.core.types import ApproxSpec, Technique
 from repro.launch import steps as steps_mod
 from repro.models.lm import Model
-from repro.obs import metrics as obs_metrics
 from repro.obs import recorder as obs_recorder
 from repro.obs import trace
 from repro.obs.metrics import percentile as _percentile
@@ -432,10 +431,14 @@ class ServingEngine:
         arriving request costs one batch-1 prefill plus a per-lane cache
         splice (`_make_lane_write`), so admission is per-request work that
         leaves ongoing lanes' KV, detector state, and the actuated knob
-        untouched -- a production multi-host engine admits the same way."""
+        untouched -- a production multi-host engine admits the same way.
+        An admission runs in the `engine.admit` span."""
         free = [i for i, r in enumerate(self.active) if r is None]
-        if not free or not self.queue:
-            return
+        if free and self.queue:
+            with trace.span("engine.admit"):
+                self._fill(free)
+
+    def _fill(self, free: List[int]):
         admitted = []
         for i in free:
             if not self.queue:
@@ -446,8 +449,6 @@ class ServingEngine:
             self.limit[i] = min(self.prompt_len + req.max_new_tokens,
                                 self.max_len)
             admitted.append(i)
-        if not admitted:
-            return
         # batch-1 surgery cannot tell a 1-slot batch dim from batchless
         # detector state, so 1-slot engines always take the full path
         if self.cache is None or self.n_slots == 1:
@@ -533,17 +534,16 @@ class ServingEngine:
         retire finished requests. Returns number of live slots.
 
         Instrumentation contract (docs/observability.md): the obs hooks
-        below are host-side timers and event appends only -- they must
-        never add a `block_until_ready`, read a traced value, or perturb
-        the serve signature. Zero extra compiles with obs on OR off is
-        pinned by `tests/test_obs.py` via `_serve._cache_size()`, and the
-        disabled-path cost by the BENCH_obs throughput-ratio gate."""
-        tr_on = trace.enabled()
+        below are profiler annotations, host-side timers and event
+        appends only -- they must never add a `block_until_ready`, read
+        a traced value, or perturb the serve signature. Zero extra
+        compiles with obs on OR off is pinned by `tests/test_obs.py` via
+        `_serve._cache_size()`, and the disabled-path cost by the
+        BENCH_obs throughput-ratio gate."""
         rec = obs_recorder.get_recorder()
-        t_tick = time.perf_counter() if (tr_on or rec is not None) else 0.0
+        t_tick = time.perf_counter() if rec is not None else 0.0
         with trace.span("engine.tick", tick=self.stats.ticks):
-            with trace.span("tick.admit"):
-                self._admit()
+            self._admit()
             live = [i for i, r in enumerate(self.active) if r is not None]
             if not live:
                 return 0
@@ -624,16 +624,10 @@ class ServingEngine:
                         self.qos.update_shards(shard_classes)
                     else:
                         self.qos.update(lane_classes)
-        if tr_on or rec is not None:
-            dt = time.perf_counter() - t_tick
-            if tr_on:
-                reg = obs_metrics.registry()
-                reg.histogram("serving.tick_s").observe(dt)
-                reg.gauge("serving.live_lanes").set(len(live))
-                reg.counter("serving.tokens_out").inc(len(live))
-            if rec is not None:
-                # close out the note the QoS update opened for this tick
-                rec.amend(tick_s=dt, live=len(live), knob=self._knob)
+        if rec is not None:
+            # close out the note the QoS update opened for this tick
+            rec.amend(tick_s=time.perf_counter() - t_tick, live=len(live),
+                      knob=self._knob)
         return len([r for r in self.active if r is not None])
 
     def run_until_drained(self, max_ticks: int = 10_000) -> EngineStats:

@@ -4,8 +4,8 @@ Pins the observability layer's contracts:
 
 - **tracing**: span nesting, instant/counter events, Chrome trace export
   shape, and the scoped `use()` tracer swap;
-- **zero-cost when disabled**: no tracer -> the span fast path returns the
-  shared null singleton and records nothing, and an instrumented
+- **zero-cost when disabled**: no tracer -> a span is a bare profiler
+  annotation and records nothing, and an instrumented
   `ServingEngine.tick()` adds ZERO compiles to the serve step whether
   tracing is on or off (`_cache_size()`, as in test_qos.py);
 - **metrics**: typed counters/gauges/histograms, the `_percentile` edge
@@ -21,6 +21,7 @@ Pins the observability layer's contracts:
   (concretization inside jit; traced value escaping into a payload) and
   the tree itself lints clean.
 """
+import glob
 import json
 import os
 
@@ -58,8 +59,8 @@ def _no_ambient_obs():
 def test_trace_disabled_is_null_and_records_nothing():
     assert not obs_trace.enabled()
     s1 = obs_trace.span("a", x=1)
-    s2 = obs_trace.span("b")
-    assert s1 is s2, "disabled fast path must return the shared singleton"
+    assert type(s1) is jax.profiler.TraceAnnotation, \
+        "disabled fast path must be a bare profiler annotation"
     with s1:
         obs_trace.event("nope")
         obs_trace.counter("nope", 1)
@@ -92,6 +93,27 @@ def test_trace_spans_nest_and_export_chrome():
     assert [c["args"]["value"] for c in cts] == [3.0, 5.0]
     assert t.counter_value("tokens") == 5.0
     assert doc["otherData"]["schema"] == obs_trace.SCHEMA_VERSION
+
+
+def test_spans_reach_the_profiler_with_and_without_a_tracer(tmp_path):
+    """A span is a profiler annotation under its bare name whether or not
+    a tracer buffers it; events and counters stay in the buffer."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("obs.bare", x=1):
+            obs_trace.event("obs.event")
+        with obs_trace.use(obs_trace.Tracer()):
+            with obs_trace.span("obs.buffered", y=2):
+                obs_trace.counter("obs.counter", 1)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = ProfileData.from_file(path)
+    names = {e.name for p in data.planes for line in p.lines
+             for e in line.events}
+    assert {"obs.bare", "obs.buffered"} <= names
+    assert not any(n.startswith(("obs.event", "obs.counter")) for n in names)
 
 
 def test_trace_use_restores_previous_tracer():
@@ -396,8 +418,8 @@ def test_instrumented_tick_adds_zero_compiles(decode_setup):
     for _ in range(4):                      # disabled again: still zero
         eng.tick()
     assert eng._serve._cache_size() == size0
-    assert obs_metrics.registry().histogram("serving.tick_s") \
-        .summary()["count"] == 4, "per-tick metrics only while tracing"
+    assert sum(r_["name"] == "engine.tick" for r_ in t.records) == 4, \
+        "spans buffered only while tracing"
 
 
 # --------------------------------------------------------------------------
