@@ -58,7 +58,7 @@ def main(argv=None):
             jnp.float32)
 
     prefill = jax.jit(steps_mod.make_prefill_step(model, max_len))
-    serve = jax.jit(steps_mod.make_serve_step(model))
+    serve = jax.jit(steps_mod.make_serve_step(model), donate_argnums=(1,))
 
     t0 = time.time()
     logits, cache = prefill(params, batch)

@@ -92,7 +92,14 @@ def make_prefill_step(model: Model, max_len: int) -> Callable:
 
 def make_serve_step(model: Model) -> Callable:
     """One-token decode: (params, cache, tokens (B,), pos) ->
-    (next_tokens, logits, new_cache)."""
+    (next_tokens, logits, new_cache).
+
+    The step consumes the cache passed to it: `new_cache` is that cache
+    with each layer's new rows written at `pos`, and nothing else of it is
+    copied. Jit it with `donate_argnums=(1,)` (as `ServingEngine` does) and
+    the write happens in place, so the caller must not use the old cache
+    afterwards; without donation XLA copies the whole cache into a new
+    buffer on every step."""
 
     def serve_step(params, cache, tokens, pos):
         logits, new_cache = model.decode_step(params, cache, tokens, pos)
@@ -123,8 +130,10 @@ def make_sharded_serve_step(model: Model, mesh, n_shards: int,
         another shard's skip decisions.
 
     Call with a cache whose TAF state has been through `shard_taf_state`.
-    Signature matches `make_serve_step`: (params, cache, tokens (B,), pos)
-    -> (next_tokens, logits, new_cache).
+    Signature and cache contract match `make_serve_step`: (params, cache,
+    tokens (B,), pos) -> (next_tokens, logits, new_cache), the step
+    consumes the cache passed to it, and jitted with `donate_argnums=(1,)`
+    it writes the new rows in place.
     """
     from jax.sharding import PartitionSpec as P
 

@@ -161,14 +161,16 @@ def _decode_step_int8(p, cfg: ModelConfig, q, k, v, x, cache: Dict, pos,
     rows + per-(b,h,s) scales; logits/context absorb the scales exactly:
       logits[.., s] = (q . k_int8[s]) * k_scale[s]
       ctx = sum_s (p[s] * v_scale[s]) * v_int8[s]
+    The quantized row goes into a local copy of the layer's cache, which is
+    attended as a whole; the rows are returned for the caller to write.
     """
     b = x.shape[0]
     kq, ks = _quantize_kv(k)
     vq, vs = _quantize_kv(v)
-    ck = jax.lax.dynamic_update_slice(cache["k"], kq, (0, 0, pos, 0))
-    cv = jax.lax.dynamic_update_slice(cache["v"], vq, (0, 0, pos, 0))
-    cks = jax.lax.dynamic_update_slice(cache["k_scale"], ks, (0, 0, pos, 0))
-    cvs = jax.lax.dynamic_update_slice(cache["v_scale"], vs, (0, 0, pos, 0))
+    rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    ck, cv, cks, cvs = (
+        jax.lax.dynamic_update_slice(cache[n], rows[n], (0, 0, pos, 0))
+        for n in ("k", "v", "k_scale", "v_scale"))
     hq = q.shape[1]
     hkv = ck.shape[1]
     group = hq // hkv
@@ -194,38 +196,45 @@ def _decode_step_int8(p, cfg: ModelConfig, q, k, v, x, cache: Dict, pos,
     ctx = ctx.reshape(b, hq, 1, d).astype(x.dtype)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1)
     out = jnp.einsum("bsh,hd->bsd", ctx, p["wo"].astype(x.dtype))
-    return out, {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
+    return out, rows
+
+
+def _perforation_keep_mask(approx: Optional[ApproxSpec], skv: int):
+    """Herded KV perforation at decode: the (S,) mask of cache positions
+    in kept blocks, or None without perforation."""
+    if approx is None or approx.technique != Technique.PERFORATION:
+        return None
+    import numpy as np
+    block = 128
+    nblocks = max(skv // block, 1)
+    keep_np = np.zeros((skv,), bool)
+    for kb in kept_indices(nblocks, approx.perforation):
+        keep_np[kb * block:(kb + 1) * block] = True
+    keep_np[skv - skv % block:] = True  # tail beyond whole blocks stays
+    return jnp.asarray(keep_np)
 
 
 def decode_step(p, cfg: ModelConfig, x: jnp.ndarray, cache: Dict,
                 pos: jnp.ndarray,
                 approx: Optional[ApproxSpec] = None) -> Tuple[jnp.ndarray, Dict]:
-    """One-token decode: x (B, 1, d); writes cache at `pos`, attends to
-    [0, pos]. Linear in cache length."""
+    """One-token decode: x (B, 1, d) -> (out (B, 1, d), rows).
+
+    `cache` is read, never written: the token attends to the cache's
+    positions < pos plus its own key and value, under one f32 softmax
+    whose max and sum run over both. `rows` holds the token's cache
+    entries at `pos` (each leaf of the cache with a sequence extent of 1,
+    in the cache's dtype) for the caller to write (`common.write_rows`).
+    Linear in cache length."""
     b = x.shape[0]
     positions = jnp.full((1,), pos, jnp.int32)
     q, k, v = _project_qkv(p, cfg, x, positions)
     if cfg.kv_cache_dtype == "int8":
         return _decode_step_int8(p, cfg, q, k, v, x, cache, pos, approx)
-    ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                      (0, 0, pos, 0))
-    cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                      (0, 0, pos, 0))
-    keep_mask = None
-    if approx is not None and approx.technique == Technique.PERFORATION:
-        # herded KV perforation at decode: mask dropped blocks of the cache
-        skv = ck.shape[2]
-        block = 128
-        nblocks = max(skv // block, 1)
-        kept = kept_indices(nblocks, approx.perforation)
-        import numpy as np
-        keep_np = np.zeros((skv,), bool)
-        for kb in kept:
-            keep_np[kb * block:(kb + 1) * block] = True
-        keep_np[skv - skv % block:] = True  # tail beyond whole blocks stays
-        keep_mask = jnp.asarray(keep_np)
-    ctx = common.decode_attention(q, ck, cv, valid_len=pos + 1,
-                                  keep_mask=keep_mask)
+    rows = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
+    ctx = common.decode_attention(
+        q, cache["k"], cache["v"], valid_len=pos,
+        keep_mask=_perforation_keep_mask(approx, cache["k"].shape[2]),
+        k_new=rows["k"], v_new=rows["v"])
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1)
     out = jnp.einsum("bsh,hd->bsd", ctx, p["wo"].astype(x.dtype))
-    return out, {"k": ck, "v": cv}
+    return out, rows

@@ -91,10 +91,12 @@ def block_prefill(p: Dict, cfg: ModelConfig, x, cache, use_moe: bool,
 
 def block_decode(p: Dict, cfg: ModelConfig, x, cache, pos, use_moe: bool,
                  approx_attn=None, approx_ffn=None):
+    """One-token decode of a block: (x, rows). The layer cache is read
+    only; `rows` are its new entries at `pos` (see `attention.decode_step`)."""
     h = common.apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     attn_mod = mla if cfg.use_mla else attention
-    out, cache = attn_mod.decode_step(p["attn"], cfg, h, cache, pos,
-                                      approx=approx_attn)
+    out, rows = attn_mod.decode_step(p["attn"], cfg, h, cache, pos,
+                                     approx=approx_attn)
     x = x + out
     h = common.apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     if use_moe:
@@ -102,7 +104,7 @@ def block_decode(p: Dict, cfg: ModelConfig, x, cache, pos, use_moe: bool,
         x = x + out
     else:
         x = x + mlp.forward(p["ffn"], cfg, h, cfg.mlp, approx=approx_ffn)
-    return x, cache
+    return x, rows
 
 
 def init_block_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Dict:

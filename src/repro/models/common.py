@@ -159,6 +159,23 @@ def scan_layers(unroll: bool, body, carry, xs):
     return carry, stacked
 
 
+def write_rows(cache, rows, pos):
+    """Write each leaf of `rows` into the same leaf of `cache` at sequence
+    position `pos`: one `dynamic_update_slice` per leaf, so a cache the
+    caller donates is updated in place. A row leaf has the cache leaf's
+    shape with a sequence extent of 1; that extent is the only axis on which
+    the two differ, whatever the module's cache layout."""
+    def one(c, r):
+        starts = [0] * c.ndim
+        for ax, (cs, rs) in enumerate(zip(c.shape, r.shape)):
+            if cs != rs:
+                starts[ax] = pos
+                break
+        return jax.lax.dynamic_update_slice(c, r.astype(c.dtype), starts)
+
+    return jax.tree.map(one, cache, rows)
+
+
 # ----------------------------------------------------------------------------
 # attention math: memory-efficient chunked softmax attention (pure jnp)
 # ----------------------------------------------------------------------------
@@ -280,12 +297,19 @@ def full_attention(q, k, v, *, causal=True, scale=None):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def decode_attention(q, k, v, *, valid_len, scale=None, keep_mask=None):
+def decode_attention(q, k, v, *, valid_len, scale=None, keep_mask=None,
+                     k_new=None, v_new=None):
     """Single-token decode attention against a (possibly oversized) cache.
 
     q: (B, Hq, 1, D); k/v: (B, Hkv, S_cache, D); positions >= valid_len are
     masked; `keep_mask` (S_cache,) additionally masks perforated KV blocks
     (herded: the same mask for every batch/head). Linear in cache length.
+
+    `k_new`/`v_new` (B, Hkv, 1, D), when given, are the token's own key and
+    value at position `valid_len`, which the cache need not hold: they join
+    the same softmax (its max and sum run over the cache's valid positions
+    and the new row), so a decode step can read the cache in place and
+    write its row afterwards.
 
     Distribution-aware form (section Perf iteration A1/A2): GQA is a grouped
     einsum -- the KV cache is NEVER head-repeated -- and the logits are
@@ -310,10 +334,21 @@ def decode_attention(q, k, v, *, valid_len, scale=None, keep_mask=None):
     logits = jnp.where(mask, logits, -1e30)
     # stable softmax over the (sharded) S axis: partial max/sum reductions
     m = jnp.max(logits, axis=-1, keepdims=True)
+    if k_new is not None:
+        logit_new = jnp.einsum("bhgd,bhsd->bhgs", qg, k_new,
+                               preferred_element_type=jnp.float32) * scale
+        keep_new = True if keep_mask is None else keep_mask[valid_len]
+        logit_new = jnp.where(keep_new, logit_new, -1e30)
+        m = jnp.maximum(m, logit_new)
     p = jnp.exp(logits - m)
     p = jnp.where(mask, p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     ctx = jnp.einsum("bhgs,bhsd->bhgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
+    if k_new is not None:
+        p_new = jnp.where(keep_new, jnp.exp(logit_new - m), 0.0)
+        l = l + p_new
+        ctx = ctx + jnp.einsum("bhgs,bhsd->bhgd", p_new.astype(v_new.dtype),
+                               v_new, preferred_element_type=jnp.float32)
     ctx = ctx / jnp.maximum(l, 1e-30)
     return ctx.reshape(b, hq, 1, dv).astype(q.dtype)

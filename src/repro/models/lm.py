@@ -14,7 +14,12 @@ Decode-time TAF (paper section 3.1.3 as a serving feature): with
 cfg.approx_decode = TAF, each transformer layer carries a TAF state machine
 across decode steps; when a layer's recent output deltas are RSD-stable the
 whole layer's compute is SKIPPED (block-level lax.cond -- the hierarchy
-insight) and the memoized delta + stale K/V are reused.
+insight) and the memoized delta + K/V rows are reused.
+
+Decode reads each layer's KV cache in place: a layer returns only its new
+rows at the decode position, and the step writes the rows of every layer
+once, after the layer scan (`common.write_rows`), into the cache it was
+given -- in place when the caller donates the cache.
 """
 from __future__ import annotations
 
@@ -289,23 +294,21 @@ def _build_transformer(cfg: ModelConfig) -> Model:
 
     def _decode_layer_taf(layer_p, layer_c, taf_c, x, pos):
         """Block-level TAF around one layer's decode step: skip the whole
-        layer (reuse memoized delta + stale K/V) while RSD-stable."""
+        layer (reuse the memoized delta and K/V rows) while RSD-stable.
+        Returns (x, rows, taf state); the layer cache is read only."""
         t = cfg.approx_decode.taf
 
         def approx_branch(op):
-            x, layer_c, taf_c = op
-            ck = jax.lax.dynamic_update_slice(
-                layer_c["k"], taf_c["memo_k"], (0, 0, pos, 0))
-            cv = jax.lax.dynamic_update_slice(
-                layer_c["v"], taf_c["memo_v"], (0, 0, pos, 0))
+            x, taf_c = op
             new_x = x + taf_c["memo_delta"][:, None, :].astype(x.dtype)
             new_taf = dict(taf_c)
             new_taf["remaining"] = jnp.maximum(taf_c["remaining"] - 1, 0)
-            return new_x, {"k": ck, "v": cv}, new_taf
+            rows = {"k": taf_c["memo_k"], "v": taf_c["memo_v"]}
+            return new_x, rows, new_taf
 
         def accurate_branch(op):
-            x, layer_c, taf_c = op
-            new_x, new_c = blocks.block_decode(
+            x, taf_c = op
+            new_x, rows = blocks.block_decode(
                 layer_p, cfg, x, layer_c, pos, use_moe=False,
                 approx_attn=cfg.approx_attention, approx_ffn=cfg.approx_ffn)
             delta = (new_x - x)[:, 0, :].astype(jnp.float32)
@@ -316,50 +319,47 @@ def _build_transformer(cfg: ModelConfig) -> Model:
             sd = jnp.std(win)
             stable = (sd / jnp.maximum(jnp.abs(mu), 1e-12) <
                       taf_c["threshold"]) & (filled >= t.history_size)
-            k_t = jax.lax.dynamic_slice(
-                new_c["k"], (0, 0, pos, 0),
-                (new_c["k"].shape[0], new_c["k"].shape[1], 1,
-                 new_c["k"].shape[3]))
-            v_t = jax.lax.dynamic_slice(
-                new_c["v"], (0, 0, pos, 0),
-                (new_c["v"].shape[0], new_c["v"].shape[1], 1,
-                 new_c["v"].shape[3]))
             new_taf = {
                 "threshold": taf_c["threshold"],
                 "window": win, "filled": filled,
                 "remaining": jnp.where(stable, t.prediction_size, 0)
                 .astype(jnp.int32),
-                "memo_delta": delta, "memo_k": k_t, "memo_v": v_t,
+                "memo_delta": delta, "memo_k": rows["k"],
+                "memo_v": rows["v"],
             }
-            return new_x, new_c, new_taf
+            return new_x, rows, new_taf
 
         return jax.lax.cond(taf_c["remaining"] > 0, approx_branch,
-                            accurate_branch, (x, layer_c, taf_c))
+                            accurate_branch, (x, taf_c))
 
     def _decode_stack(params_key, cache_key, use_moe, x, cache, pos, params):
+        """The layer scan of one decode step: the stacked cache is read
+        only, each layer yields its new rows, and the rows of every layer
+        are written once, after the scan, at `pos`. Returns (x, cache,
+        taf state or None)."""
         if _taf_decode_enabled():
             def body(h, inp):
                 layer_p, layer_c, taf_c = inp
-                h, new_c, new_taf = _decode_layer_taf(layer_p, layer_c,
-                                                      taf_c, h, pos)
-                return h, (new_c, new_taf)
+                h, rows, new_taf = _decode_layer_taf(layer_p, layer_c,
+                                                     taf_c, h, pos)
+                return h, (rows, new_taf)
 
-            x, (new_cache, new_taf) = common.scan_layers(
+            x, (rows, new_taf) = common.scan_layers(
                 cfg.unroll_layers, body, x,
                 (params[params_key], cache[cache_key], cache["taf"]))
-            return x, new_cache, new_taf
+        else:
+            def body(h, inp):
+                layer_p, layer_c = inp
+                return blocks.block_decode(
+                    layer_p, cfg, h, layer_c, pos, use_moe,
+                    approx_attn=cfg.approx_attention,
+                    approx_ffn=cfg.approx_ffn)
 
-        def body(h, inp):
-            layer_p, layer_c = inp
-            h, new_c = blocks.block_decode(
-                layer_p, cfg, h, layer_c, pos, use_moe,
-                approx_attn=cfg.approx_attention, approx_ffn=cfg.approx_ffn)
-            return h, new_c
-
-        x, new_cache = common.scan_layers(
-            cfg.unroll_layers, body, x,
-            (params[params_key], cache[cache_key]))
-        return x, new_cache, None
+            x, rows = common.scan_layers(
+                cfg.unroll_layers, body, x,
+                (params[params_key], cache[cache_key]))
+            new_taf = None
+        return x, common.write_rows(cache[cache_key], rows, pos), new_taf
 
     def decode_step(params, cache, tokens, pos):
         """tokens: (B,) -> (logits (B, V), new cache)."""
@@ -500,17 +500,17 @@ def _build_hybrid(cfg: ModelConfig) -> Model:
             h, new_mamba_c = common.scan_layers(cfg.unroll_layers,
                                                 mamba_body, h,
                                                 (group_p, mamba_c))
-            h, new_attn_c = blocks.block_decode(shared, cfg, h, attn_c, pos,
-                                                use_moe=False,
-                                                approx_attn=cfg.approx_attention)
-            return h, (new_mamba_c, new_attn_c)
+            h, attn_rows = blocks.block_decode(shared, cfg, h, attn_c, pos,
+                                               use_moe=False,
+                                               approx_attn=cfg.approx_attention)
+            return h, (new_mamba_c, attn_rows)
 
-        x, (new_mamba, new_attn) = common.scan_layers(
+        x, (new_mamba, attn_rows) = common.scan_layers(
             cfg.unroll_layers, group_body, x,
             (params["layers"]["main"], cache["mamba_main"], cache["attn"]))
         new_cache = dict(cache)
         new_cache["mamba_main"] = new_mamba
-        new_cache["attn"] = new_attn
+        new_cache["attn"] = common.write_rows(cache["attn"], attn_rows, pos)
         if tail:
             def mamba_body(hh, inp2):
                 mp, mc = inp2

@@ -134,6 +134,10 @@ def decode_step(p, cfg: ModelConfig, x, cache, pos,
     cache once (dsv3: 268 MB/dev) instead of materializing (B,H,S,192+128)
     expansions (~26 GB/dev). More latent-side FLOPs (R=512 vs 192 per
     score), the right trade for a memory-bound decode.
+
+    Returns (out, rows): the token's latent and rope key at `pos` go into
+    a local copy of the layer's cache, which is attended as a whole, and
+    are returned for the caller to write (`common.write_rows`).
     """
     m = cfg.mla
     b = x.shape[0]
@@ -143,13 +147,9 @@ def decode_step(p, cfg: ModelConfig, x, cache, pos,
     q_nope = q[..., :m.qk_nope_head_dim]
     q_rope = q[..., m.qk_nope_head_dim:]
     ckv_t, k_rope_t = _latent(p, cfg, x, positions)
-    cache = {
-        "ckv": jax.lax.dynamic_update_slice(
-            cache["ckv"], ckv_t.astype(cache["ckv"].dtype), (0, pos, 0)),
-        "k_rope": jax.lax.dynamic_update_slice(
-            cache["k_rope"], k_rope_t.astype(cache["k_rope"].dtype),
-            (0, 0, pos, 0)),
-    }
+    rows = {"ckv": ckv_t.astype(cache["ckv"].dtype),
+            "k_rope": k_rope_t.astype(cache["k_rope"].dtype)}
+    cache = common.write_rows(cache, rows, pos)     # a local copy
     ckv = cache["ckv"].astype(x.dtype)                       # (B,S,R)
     k_rope = cache["k_rope"].astype(x.dtype)[:, 0]           # (B,S,rd)
     skv = ckv.shape[1]
@@ -177,4 +177,4 @@ def decode_step(p, cfg: ModelConfig, x, cache, pos,
     w_uv = w_uv.reshape(m.kv_lora_rank, h, m.v_head_dim)
     ctx = jnp.einsum("bhqr,rhd->bhqd", ctx_lat, w_uv)        # (B,H,1,dv)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1)
-    return jnp.einsum("bsh,hd->bsd", ctx, p["wo"].astype(x.dtype)), cache
+    return jnp.einsum("bsh,hd->bsd", ctx, p["wo"].astype(x.dtype)), rows

@@ -174,7 +174,7 @@ def build(cfg: ModelConfig) -> "lm.Model":
         def body(h, inp):
             layer_p, layer_c = inp
             hh = common.layernorm(layer_p["ln1"], h, cfg.norm_eps)
-            out, new_c = attention.decode_step(
+            out, rows = attention.decode_step(
                 layer_p["self_attn"], cfg, hh, layer_c, pos,
                 approx=cfg.approx_decode)
             h = h + out
@@ -183,13 +183,12 @@ def build(cfg: ModelConfig) -> "lm.Model":
                                      positions)
             hh = common.layernorm(layer_p["ln2"], h, cfg.norm_eps)
             h = h + mlp.forward(layer_p["ffn"], cfg, hh, "gelu")
-            return h, new_c
+            return h, rows
 
-        x, new_self = common.scan_layers(cfg.unroll_layers, body, x,
-                                         (params["dec_blocks"],
-                                          cache["self"]))
+        x, rows = common.scan_layers(cfg.unroll_layers, body, x,
+                                     (params["dec_blocks"], cache["self"]))
         new_cache = dict(cache)
-        new_cache["self"] = new_self
+        new_cache["self"] = common.write_rows(cache["self"], rows, pos)
         x = common.layernorm(params["dec_norm"], x, cfg.norm_eps)
         logits = jnp.einsum("bd,dv->bv", x[:, 0], params["head"].astype(cdt))
         return logits.astype(jnp.float32), new_cache

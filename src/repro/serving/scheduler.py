@@ -16,16 +16,18 @@ under a controller-chosen spec. Each tick the engine groups live lanes by
 their request class's current knob (`batching.group_lanes` via
 `QosEngine.plan_tick`), actuates the strictest live rung by writing the
 TAF threshold into the decode cache -- a TRACED value, so knob moves never
-recompile -- and, on canary ticks, re-executes the step through the precise
-model from the same pre-tick state and feeds the compared logits to the
-quality monitor. A hard fallback zeroes both the threshold and the
-in-flight prediction counters, so "precise" takes effect on the very next
-token.
+recompile -- and, on canary ticks, runs the step through the precise model
+from the same pre-tick state, before the serve step consumes it, and feeds
+the compared logits to the quality monitor. A hard fallback zeroes both
+the threshold and the in-flight prediction counters, so "precise" takes
+effect on the very next token.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import functools
 import time
 from typing import Deque, Dict, List, Optional
 
@@ -39,6 +41,16 @@ from repro.models.lm import Model
 from repro.obs import recorder as obs_recorder
 from repro.obs import trace
 from repro.obs.metrics import percentile as _percentile
+
+
+def _without_cache(step):
+    """`step` with its cache output dropped: (next_tokens, logits). The
+    jitted program keeps the step's name, and builds no new cache."""
+    @functools.wraps(step)
+    def tokens_and_logits(params, cache, tokens, pos):
+        return step(params, cache, tokens, pos)[:2]
+
+    return tokens_and_logits
 
 
 @dataclasses.dataclass
@@ -190,11 +202,15 @@ class ServingEngine:
         # (not per-token) and its cache output is resharded once.
         self._prefill = jax.jit(steps_mod.make_prefill_step(model, max_len))
         self._lane_write = self._make_lane_write()
+        # the step consumes the live cache: each tick writes its rows into
+        # the donated buffer in place, and the engine keeps only the cache
+        # the step returns
         if mesh is not None:
             self._serve = jax.jit(steps_mod.make_sharded_serve_step(
-                model, mesh, self.n_shards, slots))
+                model, mesh, self.n_shards, slots), donate_argnums=(1,))
         else:
-            self._serve = jax.jit(steps_mod.make_serve_step(model))
+            self._serve = jax.jit(steps_mod.make_serve_step(model),
+                                  donate_argnums=(1,))
         self.cache = None
         self.tokens = self._place_tokens(jnp.zeros((slots,), jnp.int32))
         self.qos = qos
@@ -221,18 +237,21 @@ class ServingEngine:
             # the extra 'taf' entry rides through the pytree untouched.
             # In sharded mode the oracle goes through the SAME sharded
             # wrapper, so its lane->device packing (and therefore its
-            # numerics) match the approximate step bit for bit.
+            # numerics) match the approximate step bit for bit. It returns
+            # only (next_tokens, logits): it reads the pre-tick cache,
+            # which the approximate step consumes after it, and builds no
+            # cache of its own.
             from repro.models import build
             exact_model = build(dataclasses.replace(
                 model.cfg, approx_decode=ApproxSpec()))
             if mesh is not None:
-                self._serve_exact = jax.jit(
+                self._serve_exact = jax.jit(_without_cache(
                     steps_mod.make_sharded_serve_step(
-                        exact_model, mesh, self.n_shards, slots))
+                        exact_model, mesh, self.n_shards, slots)))
                 qos.enable_sharding(self.n_shards)
             else:
-                self._serve_exact = jax.jit(
-                    steps_mod.make_serve_step(exact_model))
+                self._serve_exact = jax.jit(_without_cache(
+                    steps_mod.make_serve_step(exact_model)))
         if lint:
             # opt-in approxlint pass over what this engine will actually
             # serve: the policy ladder (A004, raw entries, cross-checked
@@ -406,11 +425,13 @@ class ServingEngine:
         tokens = self._place_tokens(
             jnp.argmax(logits, axis=-1).astype(jnp.int32))
         pos = jnp.int32(self.prompt_len)
-        jax.block_until_ready(
-            self._serve(self.params, cache, tokens, pos)[0])
+        # in a tick's order: the canary reads the cache before the serve
+        # step consumes it, and the splice takes the cache the step returns
         if self._serve_exact is not None:
             jax.block_until_ready(
                 self._serve_exact(self.params, cache, tokens, pos)[0])
+        _, _, cache = self._serve(self.params, cache, tokens, pos)
+        jax.block_until_ready(cache)
         if self.n_slots > 1:
             # the admission path: batch-W prefill + multi-lane splice
             w = self._admit_width
@@ -529,6 +550,24 @@ class ServingEngine:
             trace.event("knob_move", tick=move.tick, value=move.value,
                         previous=move.previous, reason=move.reason)
 
+    def _score_canary(self, exact_logits, logits, live, lane_classes,
+                      shard_classes):
+        """Feed one canary tick's exact and approximate logits of the live
+        lanes to the quality monitor."""
+        ex = np.asarray(exact_logits)
+        ap = np.asarray(logits)
+        if self.sharded:
+            # per-shard attribution: each shard's slice is scored
+            # separately, so a canary error is credited only to the shard
+            # (and the classes) that ran under that knob
+            for s in range(self.n_shards):
+                lanes = [i for i in live if self._lane_shard(i) == s]
+                if lanes:
+                    self.qos.observe_shard(s, ex[lanes], ap[lanes],
+                                           shard_classes[s])
+        else:
+            self.qos.observe_decode(ex[live], ap[live], lane_classes)
+
     def tick(self) -> int:
         """One engine step: admit, decode one token for all active slots,
         retire finished requests. Returns number of live slots.
@@ -563,36 +602,25 @@ class ServingEngine:
                         plan = self.qos.plan_tick(lane_classes)
                         self._apply_knob(plan.knob)
             pos = int(self.pos[live].min())  # single shared timeline pos
-            pre_tokens, pre_cache = self.tokens, self.cache
-            with trace.span("tick.serve", live=len(live)):
-                self.tokens, logits, self.cache = self._serve(
-                    self.params, self.cache, self.tokens, jnp.int32(pos))
-            if self.qos is not None and self.qos.should_sample():
-                # canary: the precise oracle from the SAME pre-tick state.
-                # Score ONLY the live lanes -- idle/retired slots hold
-                # zero-padded or stale state nobody consumes, and their
-                # garbage logits would pollute the quality estimate.
-                with trace.span("tick.canary"):
-                    _, exact_logits, _ = self._serve_exact(
-                        self.params, pre_cache, pre_tokens, jnp.int32(pos))
-                    ex = np.asarray(exact_logits)
-                    ap = np.asarray(logits)
-                    if self.sharded:
-                        # per-shard attribution: each shard's slice is
-                        # scored separately, so a canary error is credited
-                        # only to the shard (and the classes) that ran
-                        # under that knob
-                        for s in range(self.n_shards):
-                            lanes = [i for i in live
-                                     if self._lane_shard(i) == s]
-                            if lanes:
-                                self.qos.observe_shard(
-                                    s, ex[lanes], ap[lanes],
-                                    shard_classes[s])
-                    else:
-                        self.qos.observe_decode(ex[live], ap[live],
-                                                lane_classes)
-                self.stats.canary_ticks += 1
+            canary = self.qos is not None and self.qos.should_sample()
+            with contextlib.ExitStack() as in_canary:
+                if canary:
+                    # canary: the precise oracle from the SAME pre-tick
+                    # state, dispatched before the serve step consumes it;
+                    # the serve step's span nests inside this one
+                    in_canary.enter_context(trace.span("tick.canary"))
+                    _, exact_logits = self._serve_exact(
+                        self.params, self.cache, self.tokens, jnp.int32(pos))
+                with trace.span("tick.serve", live=len(live)):
+                    self.tokens, logits, self.cache = self._serve(
+                        self.params, self.cache, self.tokens, jnp.int32(pos))
+                if canary:
+                    # Score ONLY the live lanes -- idle/retired slots hold
+                    # zero-padded or stale state nobody consumes, and
+                    # their garbage logits would pollute the estimate.
+                    self._score_canary(exact_logits, logits, live,
+                                       lane_classes, shard_classes)
+                    self.stats.canary_ticks += 1
             with trace.span("tick.host_read"):
                 toks = np.asarray(self.tokens)
                 if self.cache is not None and "taf" in self.cache:

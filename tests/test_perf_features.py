@@ -123,6 +123,34 @@ def test_grouped_gqa_decode_matches_repeat_form():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("perforated", [False, True],
+                         ids=["dense", "perforated"])
+def test_decode_attention_new_row_matches_written_cache(perforated):
+    """The token's own row given beside the cache (`k_new`/`v_new`, the
+    cache read only) attends like the same row written into the cache at
+    `valid_len`, perforated blocks masked alike."""
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(2, 8, 1, 16), jnp.float32)
+    k = jnp.asarray(rng.randn(2, 2, 300, 16), jnp.float32)
+    v = jnp.asarray(rng.randn(2, 2, 300, 16), jnp.float32)
+    k_row = jnp.asarray(rng.randn(2, 2, 1, 16), jnp.float32)
+    v_row = jnp.asarray(rng.randn(2, 2, 1, 16), jnp.float32)
+    keep = None
+    if perforated:      # the middle 128-block dropped, the new row in it
+        keep = jnp.asarray(np.r_[np.ones(128), np.zeros(128),
+                                 np.ones(44)].astype(bool))
+    for pos in (0, 37, 150, 299):
+        written = common.decode_attention(
+            q, k.at[:, :, pos].set(k_row[:, :, 0]),
+            v.at[:, :, pos].set(v_row[:, :, 0]), valid_len=pos + 1,
+            keep_mask=keep)
+        split = common.decode_attention(q, k, v, valid_len=pos,
+                                        keep_mask=keep, k_new=k_row,
+                                        v_new=v_row)
+        np.testing.assert_allclose(np.asarray(split), np.asarray(written),
+                                   rtol=1e-5, atol=1e-6)
+
+
 def test_grad_accumulation_matches_full_batch():
     """accum_steps=4 == full-batch step (same grads up to fp tolerance)."""
     from repro.launch import steps as steps_mod

@@ -504,6 +504,99 @@ def test_serving_latency_stats(decode_setup):
     assert fresh.stats.latency_summary()["ttft_p50_s"] is None
 
 
+def _undonated(eng):
+    """Give `eng` the serve step without cache donation: each tick then
+    leaves its input cache alive, as the engine did before donating."""
+    from repro.launch import steps
+    eng._serve = jax.jit(steps.make_serve_step(eng.model))
+    return eng
+
+
+def test_serving_donated_cache_keeps_tokens_and_canary_scores(decode_setup):
+    """The QoS engine with a donated cache serves the same tokens and the
+    same canary scores as without donation, and on every canary tick the
+    exact logits equal the precise step run on a saved copy of the
+    pre-tick cache and tokens."""
+    import jax.numpy as jnp
+    from repro.serving import ServingEngine
+    cfg, model, params = decode_setup
+    runs = {}
+    for name in ("donated", "undonated"):
+        engine_qos = qos.QosEngine(
+            serving_policy(), 2.0, sample_fraction=0.5, window=4,
+            config=ctl_config(min_samples=1, hold_ticks=1))
+        eng = ServingEngine(model, params, slots=2, max_len=32,
+                            prompt_len=8, qos=engine_qos)
+        if name == "undonated":
+            _undonated(eng)
+        saved, canaries = [], []
+        serve, observe = eng._serve, engine_qos.observe_decode
+
+        def step(params, cache, tokens, pos, serve=serve, saved=saved):
+            saved.append((jax.tree.map(jnp.copy, cache), jnp.copy(tokens),
+                          pos))
+            return serve(params, cache, tokens, pos)
+
+        def scored(ex, ap, classes, observe=observe, saved=saved,
+                   canaries=canaries):
+            canaries.append((saved[-1], np.asarray(ex),
+                             observe(ex, ap, classes)))
+            return canaries[-1][2]
+        eng._serve = step
+        engine_qos.observe_decode = scored
+        reqs = _requests(cfg, 3, gen=10, cls="batch")
+        for r in reqs:
+            eng.submit(r)
+        stats = eng.run_until_drained()
+        assert stats.finished == 3
+        runs[name] = ([r.output for r in reqs], canaries, stats, eng)
+    (out_d, can_d, stats_d, eng), (out_u, can_u, stats_u, _) = (
+        runs["donated"], runs["undonated"])
+    assert out_d == out_u
+    assert stats_d.taf_skipped == stats_u.taf_skipped > 0
+    assert len(can_d) == len(can_u) == stats_d.canary_ticks > 0
+    assert [c[2] for c in can_d] == [c[2] for c in can_u]
+    for (cache, tokens, pos), ex, _ in can_d:
+        _, exact = eng._serve_exact(eng.params, cache, tokens, pos)
+        live = np.asarray(exact)[:ex.shape[0]]
+        np.testing.assert_array_equal(ex, live)
+
+
+@pytest.mark.parametrize("engine", ["precise", "qos"])
+def test_warmup_then_ticks_consume_the_pre_tick_cache(decode_setup, engine):
+    """`warmup()` and the ticks after it never read a donated buffer: each
+    tick consumes the cache it starts from, and admission into a live
+    cache splices into the cache the serve step returned."""
+    from repro.models import build
+    from repro.serving import ServingEngine
+    cfg, model, params = decode_setup
+    kw = {}
+    if engine == "precise":
+        model = build(dataclasses.replace(cfg, approx_decode=ApproxSpec()))
+    else:
+        kw["qos"] = qos.QosEngine(serving_policy(), 2.0, sample_fraction=1.0,
+                                  window=4)
+    eng = ServingEngine(model, params, slots=2, max_len=32, prompt_len=8,
+                        **kw)
+    eng.warmup()
+    first, second = _requests(cfg, 2, gen=6)
+    eng.submit(first)
+    eng.tick()                      # the first admission prefills the batch
+    eng.submit(second)
+    eng.tick()                      # spliced into the live cache
+    for _ in range(3):
+        pre = eng.cache
+        eng.tick()
+        kv = jax.tree.leaves(pre["dense"])
+        assert kv and all(leaf.is_deleted() for leaf in kv)
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves(eng.cache))
+    stats = eng.run_until_drained()
+    assert stats.finished == 2
+    assert len(first.output) == len(second.output) == 6
+    assert eng._serve._cache_size() == 1
+
+
 # -------------------------------------------------------------- calibration
 
 def test_decode_calibration_sweeps_through_harness(decode_setup, tmp_path):

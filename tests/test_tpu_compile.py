@@ -11,7 +11,9 @@ The topology is described only inside the `topo` fixture, never while a
 module is imported: one process at a time may load the TPU library, so
 under several test workers only the worker that runs this file loads it.
 """
+import dataclasses
 import os
+import re
 import sys
 
 import pytest
@@ -115,3 +117,65 @@ def test_prefill_step_fits_one_chip(served, one_chip, no_compile_cache):
         model, chip_smoke.MAX_LEN)).lower(params, {"tokens": prompts}
                                           ).compile()
     _fits_one_chip(compiled)
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+_COMPUTATION = re.compile(r"^(ENTRY )?%(\S+) .*\{$")
+_CALLS = re.compile(r"calls=%([\w.-]+)")
+
+
+def _cache_sized_buffers(hlo: str, shapes):
+    """(computation, instruction, opcode) of each instruction of the
+    compiled text, fusion bodies left out (their values are not buffers),
+    whose result has one of `shapes`; parameters, tuple elements and
+    bitcasts are views and are left out too."""
+    fused = set()
+    for line in hlo.splitlines():
+        if " fusion(" in line:
+            fused.update(_CALLS.findall(line))
+    out, comp = [], None
+    for line in hlo.splitlines():
+        c = _COMPUTATION.match(line)
+        if c:
+            comp = ("ENTRY" if c.group(1) else "") + c.group(2)
+            continue
+        m = _INSTR.match(line)
+        if not m or comp in fused or m.group(3) in (
+                "parameter", "get-tuple-element", "bitcast"):
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims in shapes:
+            out.append((comp, m.group(1), m.group(3)))
+    return out
+
+
+@pytest.mark.parametrize("decode", ["precise", "taf"])
+def test_serve_step_writes_rows_into_the_donated_cache(
+        decode, served, one_chip, no_compile_cache):
+    """The serve step as `ServingEngine` jits it (cache donated) aliases
+    the whole cache, and no layer's (B, H, S, D) cache slice nor the
+    stacked cache is copied: the only cache-sized results are the final
+    in-place writes of the new rows."""
+    from repro.core.types import ApproxSpec
+    from repro.launch import steps
+    from repro.models import build
+    model, params, cache = served
+    if decode == "precise":
+        model = build(dataclasses.replace(model.cfg,
+                                          approx_decode=ApproxSpec()))
+        cache = {k: v for k, v in cache.items() if k != "taf"}
+    tokens = jax.ShapeDtypeStruct((chip_smoke.N_REQUESTS,), jnp.int32,
+                                  sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(steps.make_serve_step(model), donate_argnums=(1,)
+                       ).lower(params, cache, tokens, pos).compile()
+    kv = cache["dense"]
+    kv_bytes = sum(a.size * a.dtype.itemsize for a in kv.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+    stacked = {a.shape for a in kv.values()}
+    found = _cache_sized_buffers(
+        compiled.as_text(), stacked | {s[1:] for s in stacked})
+    writes = [f for f in found if f[0].startswith("ENTRY")
+              and f[2] == "dynamic-update-slice"]
+    assert len(writes) <= len(kv)
+    assert [f for f in found if f not in writes] == []
