@@ -1,6 +1,8 @@
 """Operations and bytes of a dense decoder, from the configuration's
 published shapes alone (never from the compiled program, so a change to
-the program cannot change the yardstick).
+the program cannot change the yardstick). The dense family
+(`bench/reference/dense.py`) binds them; readers reach them through
+`bench.cells.family`.
 
 Counted are the matrix products (2 operations per multiply-add) and
 causal attention's two products; norms, RoPE, softmax and the embedding
@@ -88,9 +90,3 @@ def decode_step_bytes(conf: Dict, live: int, position: int) -> int:
     length is never counted."""
     return weight_bytes(conf) + live * (position + 1) * kv_bytes_per_token(
         conf)
-
-
-def least_time_s(flops: float, nbytes: float, peaks: Dict) -> float:
-    """Roofline floor: the larger of compute time and memory time."""
-    return max(flops / peaks["bf16_flops_per_s"],
-               nbytes / peaks["hbm_bytes_per_s"])

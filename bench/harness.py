@@ -10,7 +10,9 @@
              submit a wave, admit it, read its first tokens, tick until it
              drains; each tick runs in a `bench.tick` span and is stamped
              on the host clock when it returns (the engine reads its tokens
-             back every tick, so the stamp follows the device);
+             back every tick, so the stamp follows the device); a traced
+             run profiles the window's first `TRACE_SECONDS` only, since
+             writing the trace takes about 5 s per second profiled;
     drain    the wave open at the close finishes untimed;
     check    a sample of finished requests, drawn from the seed and holding
              the longest, against the float32 reference, after the
@@ -20,7 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib
 import importlib.util
 import os
 import shutil
@@ -32,6 +33,7 @@ import numpy as np
 from bench import cells, traffic as traffic_mod
 
 TRACE_DIR = ".bench_traces"     # under the checkout, ignored by git
+TRACE_SECONDS = 20.0            # the most of a window a traced run profiles
 
 
 class NoChip(RuntimeError):
@@ -133,6 +135,7 @@ def serve_window(engine, waves, seconds: float, trace_dir=None) -> Window:
     after = None
     t0 = time.perf_counter()
     end = t0 + seconds
+    trace_end = t0 + min(seconds, TRACE_SECONDS)
     while time.perf_counter() < end:
         recs = {}
         for r in next(waves):
@@ -170,7 +173,7 @@ def serve_window(engine, waves, seconds: float, trace_dir=None) -> Window:
                 rec["knobs"].append(_lane_knob(engine, i))
             ticks.append({"stamp": now, "live": len(live), "pos": pos,
                           "traced": tracing})
-            if tracing and now >= end:
+            if tracing and now >= trace_end:
                 jax.profiler.stop_trace()
                 tracing = False
                 after = _counters(engine.stats)
@@ -224,7 +227,7 @@ def reference_readings(conf: Dict, traffic: Dict, seed: int,
     control puts first at the same positions: the control put in the
     program's place, for `judge`."""
     import jax.numpy as jnp
-    ref = importlib.import_module(f"bench.reference.{conf['reference']}")
+    ref = cells.family(conf)
     P = traffic["prompt_len"]
     T = P + traffic["output"]["max"]
     rows = np.zeros((len(sample), T), np.int32)
@@ -357,7 +360,8 @@ def per_layer(root: str, bench: Dict, workload: str,
               ctx: MetricContext) -> Dict:
     out = {}
     for m in cell_metrics(bench, workload, "per_layer"):
-        value = load_reader(root, m["name"]).read(ctx)
+        # `<quantity>.<suffix>` is read by `bench/metrics/<quantity>.py`
+        value = load_reader(root, m["name"].split(".")[0]).read(ctx)
         if value is None:
             raise MissingMetric(
                 f"per-layer metric {m['name']!r} is listed for {workload} "
@@ -392,9 +396,9 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         peaks = cells.load_peaks(root, "TPU v5 lite")
     devs = jax.devices()
 
-    from bench import weights
     cfg = cells.program_config(conf, approx=False)
-    params = weights.program_params(seed, conf, cfg.padded_vocab_size)
+    params = cells.family(conf).program_params(seed, conf,
+                                               cfg.padded_vocab_size)
     engine = build_engine(root, conf, traffic, params)
     engine.warmup()
     waves = traffic_mod.waves(traffic, seed, conf["vocab_size"])

@@ -15,6 +15,11 @@ holds more than one float32 layer beside its activations.
 `precision="fp8"` is the control: every matrix of the model is rounded to
 float8 (e4m3) with one scale per output column before use, the step a
 weight-quantising change would take below the published bfloat16.
+
+The module is the dense family (`bench.cells.family`): beside the
+reference it binds the program's field map, the program's parameter tree
+(`bench/weights.py`) and the operation and byte counts (`bench/counts.py`)
+under the family's names.
 """
 from __future__ import annotations
 
@@ -26,7 +31,29 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
+from bench import counts, weights as W
+
+# configuration keys (published names) -> the program's ModelConfig fields
+PROGRAM_FIELDS = {
+    "num_hidden_layers": "n_layers",
+    "hidden_size": "d_model",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size",
+    "head_dim": "head_dim",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "norm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+    "attention_bias": "qkv_bias",
+    "qk_norm": "qk_norm",
+    "torch_dtype": "param_dtype",
+}
+
+program_params = W.program_params
+prefill_flops = counts.prefill_flops
+decode_step_flops = counts.decode_step_flops
+decode_step_bytes = counts.decode_step_bytes
 
 _MATRICES = ("q_proj", "k_proj", "v_proj", "o_proj", "gate_proj", "up_proj",
              "down_proj", "lm_head")

@@ -2,7 +2,7 @@
 cell.
 
     python3 bench/tools/phase_readings.py --workload <cell> --seed <n> \
-        [--seconds 20]
+        [--seconds 50]
 
 The run is the one `bench/run.py --trace 1` makes, with two additions the
 benchmark does not make yet: the trace's reduction also holds `phases`
@@ -31,17 +31,20 @@ os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(ROOT, ".jax_cache")
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 QOS = ["qwen3-1.7b.qos-batch"]
-ALL = QOS + ["qwen3-1.7b.precise-batch", "qwen1.5-4b.precise-prompt"]
+PRECISE = ["qwen3-1.7b.precise-batch", "qwen1.5-4b.precise-prompt"]
 
 
 def _metric(name, layer, workloads):
+    # a metric of the QoS cell moves that cell's own `tokens_per_s.qos`
+    moves = "tokens_per_s.qos" if workloads == QOS else "tokens_per_s"
     return {"name": name, "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": layer,
-            "moves": "tokens_per_s", "workloads": workloads}
+            "moves": moves, "workloads": workloads}
 
 
 METRICS = [
-    _metric("readback_ms_per_tick", "engine", ALL),
+    _metric("readback_ms_per_tick", "engine", PRECISE),
+    _metric("readback_ms_per_tick.qos", "engine", QOS),
     _metric("canary_host_ms_per_tick", "qos", QOS),
     _metric("qos_host_ms_per_tick", "qos", QOS),
     _metric("taf_step_ms", "serve step", QOS),
@@ -53,7 +56,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, default=20.0)
+    ap.add_argument("--seconds", type=float, default=50.0)
     args = ap.parse_args(argv)
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)

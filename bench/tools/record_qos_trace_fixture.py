@@ -34,12 +34,13 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=0.05)
     args = ap.parse_args(argv)
     from conftest import make_bench_root
-    from bench import cells, harness, traffic, weights
+    from bench import cells, harness, traffic
     root = make_bench_root(tempfile.mkdtemp(), [CELL])
     conf = cells.load_config(root, CELL[0])
     mix = cells.load_traffic(root, CELL[1])
     cfg = cells.program_config(conf, approx=False)
-    params = weights.program_params(0, conf, cfg.padded_vocab_size)
+    params = cells.family(conf).program_params(0, conf,
+                                               cfg.padded_vocab_size)
     engine = harness.build_engine(root, conf, mix, params)
     engine.warmup()
     waves = traffic.waves(mix, 0, conf["vocab_size"])

@@ -19,8 +19,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 SMOKE = {
-    "name": "qwen3-smoke", "program": "qwen3-1.7b", "hidden_size": 64,
-    "intermediate_size": 128, "num_hidden_layers": 2,
+    "name": "qwen3-smoke", "program": "qwen3-1.7b", "reference": "dense",
+    "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
     "vocab_size": 256, "rope_theta": 1000000.0, "rms_norm_eps": 1e-06,
     "tie_word_embeddings": True, "attention_bias": False, "qk_norm": True,
@@ -32,9 +32,10 @@ TRAFFIC = {"engine": "precise", "slots": 4, "prompt_len": 16,
 
 
 def main(out: str) -> int:
-    from bench import cells, harness, traffic, weights
+    from bench import cells, harness, traffic
     cfg = cells.program_config(SMOKE, approx=False)
-    params = weights.program_params(0, SMOKE, cfg.padded_vocab_size)
+    params = cells.family(SMOKE).program_params(0, SMOKE,
+                                                cfg.padded_vocab_size)
     engine = harness.build_engine(ROOT, SMOKE, TRAFFIC, params)
     engine.warmup()
     waves = traffic.waves(TRAFFIC, 0, SMOKE["vocab_size"])

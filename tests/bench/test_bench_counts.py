@@ -68,9 +68,9 @@ def test_prefill_flops_by_hand(qwen15):
 
 def test_least_time_takes_the_larger_bound():
     peaks = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
-    assert counts.least_time_s(1000.0, 10.0, peaks) == 10.0
-    assert counts.least_time_s(100.0, 100.0, peaks) == 10.0
-    assert counts.least_time_s(100.0, 50.0, peaks) == 5.0
+    assert cells.least_time_s(1000.0, 10.0, peaks) == 10.0
+    assert cells.least_time_s(100.0, 100.0, peaks) == 10.0
+    assert cells.least_time_s(100.0, 50.0, peaks) == 5.0
 
 
 def test_peak_table_knows_v5e_and_refuses_others():

@@ -81,6 +81,51 @@ def test_cell_bound_metric_reads_its_quantity(tmp_path):
     assert m["tpot_ms_p95.qos"] == m["tpot_ms_p95"]
 
 
+def test_cell_bound_per_layer_metric_reads_its_quantity(tmp_path):
+    """A per-layer metric split by cell, `device_idle_share.qos`, is read
+    by its quantity's reader, `bench/metrics/device_idle_share.py`."""
+    from conftest import make_bench_root
+    root = make_bench_root(tmp_path, [("qwen3-smoke", "smoke-qos")],
+                           per_layer=["device_idle_share"])
+    bench = cells.load_benchmark(root)
+    bench["per_layer"] = [dict(m, name="device_idle_share.qos",
+                               workloads=["qwen3-smoke.smoke-qos"])
+                          for m in bench["per_layer"]]
+    ctx = harness.MetricContext(
+        trace={"ticks": 21, "window_s": 2.0, "busy_s": 1.5},
+        counters={}, ticks=[], admits=[], conf={}, traffic={}, peaks={},
+        chips=1)
+    out = harness.per_layer(root, bench, "qwen3-smoke.smoke-qos", ctx)
+    assert out == {"device_idle_share.qos": {"value": 25.0, "unit": "%"}}
+
+
+def test_traced_run_profiles_the_window_start_only(bench_root, tmp_path,
+                                                   monkeypatch):
+    """The profiler covers the window's first `TRACE_SECONDS`; the window
+    runs on untraced to its end, and the counters cover the traced ticks."""
+    from bench import traffic as traffic_mod
+    monkeypatch.setattr(harness, "TRACE_SECONDS", 0.5)
+    conf = cells.load_config(bench_root, "qwen3-smoke")
+    traffic = cells.load_traffic(bench_root, "smoke-precise")
+    cfg = cells.program_config(conf, approx=False)
+    params = cells.family(conf).program_params(SEED, conf,
+                                               cfg.padded_vocab_size)
+    engine = harness.build_engine(bench_root, conf, traffic, params)
+    engine.warmup()
+    waves = traffic_mod.waves(traffic, SEED, conf["vocab_size"])
+    harness.warm_wave(engine, waves)
+    t0 = time.perf_counter()
+    win = harness.serve_window(engine, waves, 2.0, str(tmp_path / "trace"))
+    traced = [t["traced"] for t in win.ticks]
+    assert traced[0] and not traced[-1]
+    assert traced == sorted(traced, reverse=True)
+    last_traced = win.ticks[sum(traced) - 1]["stamp"] - t0
+    assert 0.5 <= last_traced < 2.0
+    assert win.ticks[-1]["stamp"] - t0 >= 2.0
+    assert win.counters["ticks"] == sum(traced)
+    assert os.listdir(tmp_path / "trace")
+
+
 def test_unknown_device_kind_is_an_error(bench_root):
     with pytest.raises(KeyError, match="device_kind"):
         cells.load_peaks(bench_root, "TPU v99")
@@ -109,6 +154,26 @@ def test_every_cell_names_files_that_exist():
             doc = cells.load_policy_doc(ROOT, w["config"])
             assert "default" in doc["targets"]
     for m in bench["per_layer"]:
-        assert hasattr(harness.load_reader(ROOT, m["name"]), "read")
+        reader = harness.load_reader(ROOT, m["name"].split(".")[0])
+        assert hasattr(reader, "read")
     for c in bench["configs"]:
         assert os.path.exists(os.path.join(ROOT, c["file"]))
+
+
+def test_per_layer_metrics_move_a_metric_their_cells_report():
+    """Each per-layer metric moves one end-to-end metric, named in full,
+    and every cell it is listed for reports that metric. A cell reports
+    one metric per quantity: `tpot_ms_p95.qos` is the quantity
+    `tpot_ms_p95` under a bound of its own."""
+    bench = cells.load_benchmark(ROOT)
+    names = {w["name"] for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    for w in names:
+        reported = {m["name"] for m in harness.cell_metrics(
+            bench, w, "end_to_end")}
+        assert "setup_s" in reported and len(reported) >= 2, w
+        assert len({n.split(".")[0] for n in reported}) == len(reported), w
+    for m in bench["per_layer"]:
+        assert m["moves"] in e2e, m["name"]
+        moved = set(e2e[m["moves"]].get("workloads", names))
+        assert set(m.get("workloads", moved)) <= moved, m["name"]

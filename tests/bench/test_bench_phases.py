@@ -146,7 +146,9 @@ def _context(red, traffic, ticks):
 
 
 def _read(name, ctx):
-    return harness.load_reader(ROOT, name).read(ctx)
+    """The listed metric `name` as the harness reads it: by the reader of
+    its quantity, the name up to its first `.`."""
+    return harness.load_reader(ROOT, name.split(".")[0]).read(ctx)
 
 
 @pytest.mark.parametrize("where", ["", "qos"])
@@ -223,12 +225,12 @@ def test_engine_spans_reach_the_profiler(tmp_path):
     engine's spans sit on the host line of the `bench.*` spans, each
     inside one, and there is one `tick.canary` per canary tick."""
     from conftest import make_bench_root
-    from bench import traffic, weights
+    from bench import traffic
     cell = ("qwen3-smoke", "smoke-qos")
     root = make_bench_root(tmp_path / "bench", [cell])
     conf = cells.load_config(root, cell[0])
     mix = cells.load_traffic(root, cell[1])
-    params = weights.program_params(
+    params = cells.family(conf).program_params(
         0, conf, cells.program_config(conf, approx=False).padded_vocab_size)
     engine = harness.build_engine(root, conf, mix, params)
     engine.warmup()
