@@ -14,9 +14,10 @@ from repro.core.types import ApproxSpec
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-style Multi-head Latent Attention dims."""
+    """DeepSeek-style Multi-head Latent Attention dims. `q_lora_rank` None:
+    queries are one plain projection (no low-rank query path, no q norm)."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -34,6 +35,19 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_group_size: int = 512  # tokens per dispatch group (memory knob)
     aux_loss_coef: float = 0.001
+    # dropless routing: every routed (token, expert) pair is computed, by
+    # DeepSeek-V3's `noaux_tc` router (`moe.route`); off, the GShard
+    # capacity path (softmax router) drops overflow
+    dropless: bool = False
+    routed_scaling_factor: float = 1.0   # the dropless router's weights x
+    # expert parallelism: this device holds experts [expert_offset,
+    # expert_offset + n_held) of the n_experts the router scores (0: all)
+    n_held: int = 0
+    expert_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,8 +154,9 @@ class ModelConfig:
         def attn_params():
             if self.use_mla:
                 m = self.mla
-                q = d * m.q_lora_rank + m.q_lora_rank * self.n_heads * (
-                    m.qk_nope_head_dim + m.qk_rope_head_dim)
+                qk = self.n_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+                q = (d * qk if m.q_lora_rank is None
+                     else d * m.q_lora_rank + m.q_lora_rank * qk)
                 kv = d * (m.kv_lora_rank + m.qk_rope_head_dim) + \
                     m.kv_lora_rank * self.n_heads * (
                         m.qk_nope_head_dim + m.v_head_dim)
@@ -163,7 +178,7 @@ class ModelConfig:
             n_moe = self.n_layers - m.n_dense_layers
             total += self.n_layers * attn_params()
             total += m.n_dense_layers * dense_ffn(m.d_ff_dense or self.d_ff)
-            total += n_moe * (m.n_experts + m.n_shared_experts) * \
+            total += n_moe * (m.held + m.n_shared_experts) * \
                 dense_ffn(m.d_ff_expert)
             total += n_moe * d * m.n_experts  # router
         elif self.family == "ssm":
@@ -196,7 +211,7 @@ class ModelConfig:
         total = self.param_count()
         n_moe = self.n_layers - m.n_dense_layers
         mult = 3 if self.mlp == "gated_silu" else 2
-        all_experts = n_moe * m.n_experts * mult * self.d_model * m.d_ff_expert
+        all_experts = n_moe * m.held * mult * self.d_model * m.d_ff_expert
         active_experts = n_moe * m.experts_per_token * mult * \
             self.d_model * m.d_ff_expert
         return total - all_experts + active_experts

@@ -15,6 +15,7 @@ _MODULES: Dict[str, str] = {
     "deepseek-7b": "repro.configs.deepseek_7b",
     "deepseek-v3-671b": "repro.configs.deepseek_v3_671b",
     "olmoe-1b-7b": "repro.configs.olmoe_1b_7b",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
     "pixtral-12b": "repro.configs.pixtral_12b",
     "whisper-large-v3": "repro.configs.whisper_large_v3",
 }

@@ -112,9 +112,10 @@ def make_serve_step(model: Model) -> Callable:
 def make_sharded_serve_step(model: Model, mesh, n_shards: int,
                             batch_size: int) -> Callable:
     """The serve step shard_map'd over the mesh's data axes: request lanes
-    are data-parallel, and the decode cache's TAF detector state (see
-    `models.lm.shard_taf_state`) carries a leading LOGICAL-shard dim that is
-    vmapped inside each device, so:
+    are data-parallel, and the decode cache's per-shard state, the TAF
+    detector and the expert row counter (see `models.lm.shard_decode_state`),
+    carries a leading LOGICAL-shard dim that is vmapped inside each device,
+    so:
 
       * n_shards is decoupled from the device count (any multiple of the
         mesh's data extent): the same engine config runs on 1 device and
@@ -129,7 +130,7 @@ def make_sharded_serve_step(model: Model, mesh, n_shards: int,
         shard's OWN lanes, so one shard's regime change cannot flip
         another shard's skip decisions.
 
-    Call with a cache whose TAF state has been through `shard_taf_state`.
+    Call with a cache that has been through `shard_decode_state`.
     Signature and cache contract match `make_serve_step`: (params, cache,
     tokens (B,), pos) -> (next_tokens, logits, new_cache), the step
     consumes the cache passed to it, and jitted with `donate_argnums=(1,)`

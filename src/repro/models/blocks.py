@@ -4,6 +4,7 @@ so the layer loop is a lax.scan (compile-time O(1) in depth).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -50,6 +51,12 @@ def _pin_residual(x, cfg: ModelConfig):
     return common.shard_hint(x, common.data_axes_hint(), None, None)
 
 
+def _attn_scope(cfg: ModelConfig):
+    """The `mla` name scope around latent attention (it reaches the
+    compiled HLO's op metadata); dense attention keeps its names."""
+    return jax.named_scope("mla") if cfg.use_mla else contextlib.nullcontext()
+
+
 def block_forward(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
                   positions: jnp.ndarray, use_moe: bool,
                   approx_attn: Optional[ApproxSpec] = None,
@@ -58,14 +65,15 @@ def block_forward(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     """Returns (x, aux_loss)."""
     x = _pin_residual(x, cfg)
     h = common.apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
-    attn_mod = mla if cfg.use_mla else attention
-    x = _pin_residual(
-        x + attn_mod.forward(p["attn"], cfg, h, positions, causal=causal,
-                             approx=approx_attn), cfg)
+    with _attn_scope(cfg):
+        attn_mod = mla if cfg.use_mla else attention
+        out = attn_mod.forward(p["attn"], cfg, h, positions, causal=causal,
+                               approx=approx_attn)
+    x = _pin_residual(x + out, cfg)
     h = common.apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     aux = jnp.float32(0.0)
     if use_moe:
-        out, aux = moe.forward(p["moe"], cfg, h, approx=approx_ffn)
+        out, aux, _ = moe.forward(p["moe"], cfg, h, approx=approx_ffn)
         x = _pin_residual(x + out, cfg)
     else:
         x = _pin_residual(
@@ -77,12 +85,14 @@ def block_forward(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
 def block_prefill(p: Dict, cfg: ModelConfig, x, cache, use_moe: bool,
                   approx_attn=None, approx_ffn=None):
     h = common.apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
-    attn_mod = mla if cfg.use_mla else attention
-    out, cache = attn_mod.prefill(p["attn"], cfg, h, cache, approx=approx_attn)
+    with _attn_scope(cfg):
+        attn_mod = mla if cfg.use_mla else attention
+        out, cache = attn_mod.prefill(p["attn"], cfg, h, cache,
+                                      approx=approx_attn)
     x = x + out
     h = common.apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     if use_moe:
-        out, _ = moe.forward(p["moe"], cfg, h, approx=approx_ffn)
+        out, _, _ = moe.forward(p["moe"], cfg, h, approx=approx_ffn)
         x = x + out
     else:
         x = x + mlp.forward(p["ffn"], cfg, h, cfg.mlp, approx=approx_ffn)
@@ -91,20 +101,24 @@ def block_prefill(p: Dict, cfg: ModelConfig, x, cache, use_moe: bool,
 
 def block_decode(p: Dict, cfg: ModelConfig, x, cache, pos, use_moe: bool,
                  approx_attn=None, approx_ffn=None):
-    """One-token decode of a block: (x, rows). The layer cache is read
-    only; `rows` are its new entries at `pos` (see `attention.decode_step`)."""
+    """One-token decode of a block: (x, rows, routed). The layer cache is
+    read only; `rows` are its new entries at `pos` (see
+    `attention.decode_step`); `routed` are a dropless expert layer's rows
+    routed to each held expert (`moe.dropless_forward`), else None."""
     h = common.apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
-    attn_mod = mla if cfg.use_mla else attention
-    out, rows = attn_mod.decode_step(p["attn"], cfg, h, cache, pos,
-                                     approx=approx_attn)
+    with _attn_scope(cfg):
+        attn_mod = mla if cfg.use_mla else attention
+        out, rows = attn_mod.decode_step(p["attn"], cfg, h, cache, pos,
+                                         approx=approx_attn)
     x = x + out
     h = common.apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
+    routed = None
     if use_moe:
-        out, _ = moe.forward(p["moe"], cfg, h, approx=approx_ffn)
+        out, _, routed = moe.forward(p["moe"], cfg, h, approx=approx_ffn)
         x = x + out
     else:
         x = x + mlp.forward(p["ffn"], cfg, h, cfg.mlp, approx=approx_ffn)
-    return x, rows
+    return x, rows, routed
 
 
 def init_block_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Dict:

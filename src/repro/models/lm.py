@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.types import Level, Technique
@@ -104,6 +105,17 @@ class Model:
 TAF_SHARD_STATE = ("threshold", "window", "filled", "remaining")
 
 
+# The row counter of a dropless expert stack (`moe.dropless_forward`): per
+# expert layer, the rows routed to each held expert, (n_moe, E_held) int32,
+# summed over every decode step since the cache was made (prefill counts
+# nothing). It has no batch dim, so the admission splice keeps its live
+# value; under a sharded engine it leads with a shard dim, like the TAF
+# detector state. An int32 count wraps after 2**31 rows to one expert: at
+# Moonlight's mean of 128 lanes x 6 / 64 = 12 rows a step, after 1.8e8
+# steps (about 40 days of 21 ms steps).
+MOE_ROWS = "moe_rows"
+
+
 def shard_taf_state(cache, n_shards: int):
     """Return `cache` with the TAF detector state replicated per shard.
 
@@ -121,6 +133,38 @@ def shard_taf_state(cache, n_shards: int):
         leaf = taf[key]
         taf[key] = jnp.broadcast_to(leaf[None], (n_shards,) + leaf.shape)
     return dict(cache, taf=taf)
+
+
+def shard_decode_state(cache, n_shards: int):
+    """Return a freshly prefilled `cache` with its per-shard state led by a
+    shard dim: the TAF detector state (`shard_taf_state`) and the expert
+    row counter, which counts from 0 on every shard. The sharded serve step
+    vmaps the decode step over that dim."""
+    cache = shard_taf_state(cache, n_shards)
+    if MOE_ROWS in cache:
+        rows = cache[MOE_ROWS]
+        cache = dict(cache, **{MOE_ROWS: jnp.zeros((n_shards,) + rows.shape,
+                                                   rows.dtype)})
+    return cache
+
+
+def moe_row_counts(cfg: ModelConfig, cache, steps: int, lanes: int):
+    """The expert row counts of a dropless expert stack over `steps` decode
+    steps of `cache`, each of `lanes` lanes per shard: (routed, computed).
+    `routed` (n_moe, E_held) int64 is the device counter summed over
+    shards, read off the device here; `computed` is worked out on the host,
+    since every step computes the same rows (`moe.rows_computed`), or
+    equals the routed rows where the grouped product runs. None for a
+    cache without the counter."""
+    if cache is None or MOE_ROWS not in cache:
+        return None
+    routed = np.asarray(jax.device_get(cache[MOE_ROWS]), np.int64)
+    n_shards = routed.shape[0] if routed.ndim == 3 else 1
+    routed = routed.reshape((-1,) + routed.shape[-2:]).sum(axis=0)
+    per_layer = moe.rows_computed(cfg, lanes, cfg.approx_ffn)
+    if per_layer is None:
+        return routed, int(routed.sum())
+    return routed, steps * n_shards * routed.shape[0] * per_layer
 
 
 # ============================================================================
@@ -236,6 +280,8 @@ def _build_transformer(cfg: ModelConfig) -> Model:
                                                   cdt))(jnp.arange(n_moe))
         if _taf_decode_enabled():
             cache["taf"] = _taf_init_cache(batch_size, cfg.n_layers)
+        if n_moe and cfg.moe.dropless:
+            cache[MOE_ROWS] = jnp.zeros((n_moe, cfg.moe.held), jnp.int32)
         return cache
 
     def _prefill_stack(params_key, cache_key, use_moe, x, cache, params):
@@ -308,7 +354,7 @@ def _build_transformer(cfg: ModelConfig) -> Model:
 
         def accurate_branch(op):
             x, taf_c = op
-            new_x, rows = blocks.block_decode(
+            new_x, rows, _ = blocks.block_decode(
                 layer_p, cfg, x, layer_c, pos, use_moe=False,
                 approx_attn=cfg.approx_attention, approx_ffn=cfg.approx_ffn)
             delta = (new_x - x)[:, 0, :].astype(jnp.float32)
@@ -336,7 +382,7 @@ def _build_transformer(cfg: ModelConfig) -> Model:
         """The layer scan of one decode step: the stacked cache is read
         only, each layer yields its new rows, and the rows of every layer
         are written once, after the scan, at `pos`. Returns (x, cache,
-        taf state or None)."""
+        taf state or None, per-layer row counters or None)."""
         if _taf_decode_enabled():
             def body(h, inp):
                 layer_p, layer_c, taf_c = inp
@@ -347,34 +393,39 @@ def _build_transformer(cfg: ModelConfig) -> Model:
             x, (rows, new_taf) = common.scan_layers(
                 cfg.unroll_layers, body, x,
                 (params[params_key], cache[cache_key], cache["taf"]))
+            counts = None
         else:
             def body(h, inp):
                 layer_p, layer_c = inp
-                return blocks.block_decode(
+                h, rows, counts = blocks.block_decode(
                     layer_p, cfg, h, layer_c, pos, use_moe,
                     approx_attn=cfg.approx_attention,
                     approx_ffn=cfg.approx_ffn)
+                return h, (rows, counts)
 
-            x, rows = common.scan_layers(
+            x, (rows, counts) = common.scan_layers(
                 cfg.unroll_layers, body, x,
                 (params[params_key], cache[cache_key]))
             new_taf = None
-        return x, common.write_rows(cache[cache_key], rows, pos), new_taf
+        return (x, common.write_rows(cache[cache_key], rows, pos), new_taf,
+                counts)
 
     def decode_step(params, cache, tokens, pos):
         """tokens: (B,) -> (logits (B, V), new cache)."""
         x = jnp.take(params["embed"], tokens[:, None], axis=0).astype(cdt)
         new_cache = dict(cache)
         if n_dense:
-            x, nc, ntaf = _decode_stack("dense_blocks", "dense", False,
-                                        x, cache, pos, params)
+            x, nc, ntaf, _ = _decode_stack("dense_blocks", "dense", False,
+                                           x, cache, pos, params)
             new_cache["dense"] = nc
             if ntaf is not None:
                 new_cache["taf"] = ntaf
         if n_moe:
-            x, nc, _ = _decode_stack("moe_blocks", "moe", True,
-                                     x, cache, pos, params)
+            x, nc, _, counts = _decode_stack("moe_blocks", "moe", True,
+                                             x, cache, pos, params)
             new_cache["moe"] = nc
+            if counts is not None:
+                new_cache[MOE_ROWS] = cache[MOE_ROWS] + counts
         x = common.apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
         logits = jnp.einsum("bd,dv->bv", x[:, 0], _head_w(params).astype(cdt))
         return logits.astype(jnp.float32), new_cache
@@ -500,9 +551,9 @@ def _build_hybrid(cfg: ModelConfig) -> Model:
             h, new_mamba_c = common.scan_layers(cfg.unroll_layers,
                                                 mamba_body, h,
                                                 (group_p, mamba_c))
-            h, attn_rows = blocks.block_decode(shared, cfg, h, attn_c, pos,
-                                               use_moe=False,
-                                               approx_attn=cfg.approx_attention)
+            h, attn_rows, _ = blocks.block_decode(
+                shared, cfg, h, attn_c, pos, use_moe=False,
+                approx_attn=cfg.approx_attention)
             return h, (new_mamba_c, attn_rows)
 
         x, (new_mamba, attn_rows) = common.scan_layers(

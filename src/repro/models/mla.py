@@ -1,11 +1,12 @@
 """Multi-head Latent Attention (DeepSeek-V2/V3).
 
-Queries and KV are low-rank compressed; the KV cache stores ONLY the
-compressed latent (kv_lora_rank) plus the shared rope key (qk_rope_head_dim)
-per position -- 576 floats/token for dsv3 instead of 2*128*128: the reason
-decode_32k fits. Decode recomputes k/v from the cached latent (the
-"naive" expansion; the absorbed-matmul variant is a hillclimb candidate
-recorded in EXPERIMENTS.md section Perf).
+KV are low-rank compressed, and queries too unless `q_lora_rank` is None
+(one plain projection, no query norm: Moonlight). The KV cache stores ONLY
+the compressed latent (kv_lora_rank) plus the shared rope key
+(qk_rope_head_dim) per position -- 576 floats/token for dsv3 instead of
+2*128*128: the reason decode_32k fits. Prefill expands K/V from the latent
+(the published form); decode absorbs the expansion into the query and the
+output (`decode_step`).
 """
 from __future__ import annotations
 
@@ -24,11 +25,17 @@ def init_params(key, cfg: ModelConfig, dtype) -> Dict:
     h = cfg.n_heads
     ks = jax.random.split(key, 7)
     qk_head = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank is None:
+        q = {"w_q": common.dense_init(ks[0], (d, h * qk_head), dtype=dtype)}
+    else:
+        q = {
+            "w_dq": common.dense_init(ks[0], (d, m.q_lora_rank), dtype=dtype),
+            "q_norm": common.rmsnorm_params(m.q_lora_rank, dtype),
+            "w_uq": common.dense_init(ks[1], (m.q_lora_rank, h * qk_head),
+                                      dtype=dtype),
+        }
     return {
-        "w_dq": common.dense_init(ks[0], (d, m.q_lora_rank), dtype=dtype),
-        "q_norm": common.rmsnorm_params(m.q_lora_rank, dtype),
-        "w_uq": common.dense_init(ks[1], (m.q_lora_rank, h * qk_head),
-                                  dtype=dtype),
+        **q,
         "w_dkv": common.dense_init(
             ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim), dtype=dtype),
         "kv_norm": common.rmsnorm_params(m.kv_lora_rank, dtype),
@@ -44,12 +51,17 @@ def _queries(p, cfg: ModelConfig, x, positions):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
-    w_dq = common.shard_hint(p["w_dq"], None, "model")
-    cq = common.rmsnorm(p["q_norm"],
-                        jnp.einsum("bsd,dr->bsr", x, w_dq.astype(x.dtype)),
-                        cfg.norm_eps)
-    w_uq = common.shard_hint(p["w_uq"], None, "model")
-    q = jnp.einsum("bsr,rh->bsh", cq, w_uq.astype(x.dtype))
+    if m.q_lora_rank is None:
+        w_q = common.shard_hint(p["w_q"], None, "model")
+        q = jnp.einsum("bsd,dh->bsh", x, w_q.astype(x.dtype))
+    else:
+        w_dq = common.shard_hint(p["w_dq"], None, "model")
+        cq = common.rmsnorm(p["q_norm"],
+                            jnp.einsum("bsd,dr->bsr", x,
+                                       w_dq.astype(x.dtype)),
+                            cfg.norm_eps)
+        w_uq = common.shard_hint(p["w_uq"], None, "model")
+        q = jnp.einsum("bsr,rh->bsh", cq, w_uq.astype(x.dtype))
     q = q.reshape(b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q = q.transpose(0, 2, 1, 3)
     q_nope = q[..., :m.qk_nope_head_dim]
@@ -135,9 +147,11 @@ def decode_step(p, cfg: ModelConfig, x, cache, pos,
     expansions (~26 GB/dev). More latent-side FLOPs (R=512 vs 192 per
     score), the right trade for a memory-bound decode.
 
-    Returns (out, rows): the token's latent and rope key at `pos` go into
-    a local copy of the layer's cache, which is attended as a whole, and
-    are returned for the caller to write (`common.write_rows`).
+    The layer cache is read in place: its positions < `pos` and the
+    token's own latent and rope key (which the cache need not hold yet)
+    enter one float32 softmax, as `common.decode_attention(k_new=...)`
+    does for dense attention. Returns (out, rows): the token's rows at
+    `pos`, for the caller to write (`common.write_rows`).
     """
     m = cfg.mla
     b = x.shape[0]
@@ -149,28 +163,38 @@ def decode_step(p, cfg: ModelConfig, x, cache, pos,
     ckv_t, k_rope_t = _latent(p, cfg, x, positions)
     rows = {"ckv": ckv_t.astype(cache["ckv"].dtype),
             "k_rope": k_rope_t.astype(cache["k_rope"].dtype)}
-    cache = common.write_rows(cache, rows, pos)     # a local copy
     ckv = cache["ckv"].astype(x.dtype)                       # (B,S,R)
     k_rope = cache["k_rope"].astype(x.dtype)[:, 0]           # (B,S,rd)
+    ckv_new = rows["ckv"].astype(x.dtype)                    # (B,1,R)
+    k_rope_new = rows["k_rope"].astype(x.dtype)[:, 0]        # (B,1,rd)
     skv = ckv.shape[1]
     da = common.data_axes_hint()
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5
     # absorb W_uk into the query: (R, H*nope) -> (H, nope, R)
     w_uk = common.shard_hint(p["w_uk"], None, "model").astype(x.dtype)
     w_uk = w_uk.reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
     q_lat = jnp.einsum("bhqd,rhd->bhqr", q_nope, w_uk)       # (B,H,1,R)
-    logits = jnp.einsum("bhqr,bsr->bhqs", q_lat, ckv,
-                        preferred_element_type=jnp.float32)
-    logits = logits + jnp.einsum("bhqd,bsd->bhqs", q_rope, k_rope,
-                                 preferred_element_type=jnp.float32)
-    logits = logits / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
-    logits = common.shard_hint(logits, da, None, None, "model")
-    mask = jnp.arange(skv)[None, None, None, :] <= pos
+
+    def scores(lat, rope):
+        s = jnp.einsum("bhqr,bsr->bhqs", q_lat, lat,
+                       preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("bhqd,bsd->bhqs", q_rope, rope,
+                           preferred_element_type=jnp.float32)
+        return s / scale
+
+    logits = common.shard_hint(scores(ckv, k_rope), da, None, None, "model")
+    logit_new = scores(ckv_new, k_rope_new)                  # (B,H,1,1)
+    mask = jnp.arange(skv)[None, None, None, :] < pos
     logits = jnp.where(mask, logits, -1e30)
-    mx = jnp.max(logits, axis=-1, keepdims=True)
-    pr = jnp.exp(logits - mx)
-    l = jnp.sum(pr, axis=-1, keepdims=True)
+    mx = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), logit_new)
+    pr = jnp.where(mask, jnp.exp(logits - mx), 0.0)
+    p_new = jnp.exp(logit_new - mx)
+    l = jnp.sum(pr, axis=-1, keepdims=True) + p_new
     ctx_lat = jnp.einsum("bhqs,bsr->bhqr", pr.astype(x.dtype), ckv,
                          preferred_element_type=jnp.float32)
+    ctx_lat = ctx_lat + jnp.einsum("bhqs,bsr->bhqr", p_new.astype(x.dtype),
+                                   ckv_new,
+                                   preferred_element_type=jnp.float32)
     ctx_lat = (ctx_lat / jnp.maximum(l, 1e-30)).astype(x.dtype)
     # absorb W_uv on the way out: (R, H*dv) -> (H, R, dv)
     w_uv = common.shard_hint(p["w_uv"], None, "model").astype(x.dtype)

@@ -28,7 +28,7 @@ PyTree = Any
 
 # parameter-name classes (last path component)
 _COL = {"wq", "wk", "wv", "w_gate", "w_up", "w_in", "w_r", "w_k", "w_v",
-        "w_g", "w_uq", "w_uk", "w_uv", "w_dq", "w_dkv", "head", "proj",
+        "w_g", "w_q", "w_uq", "w_uk", "w_uv", "w_dq", "w_dkv", "head", "proj",
         "decay_A", "decay_B"}
 _ROW = {"wo", "w_down", "w_out", "w_o"}
 _EMBED = {"embed"}
@@ -177,18 +177,21 @@ def replicated(mesh: Mesh) -> NamedSharding:
 def decode_shard_axis(path, shape, batch_size: int) -> Optional[Tuple[str, int]]:
     """Classify one decode-cache leaf for data-parallel serving.
 
-    Returns ("state", 0) for TAF detector-state leaves (per-shard, leading
-    shard dim added by `models.lm.shard_taf_state`), ("batch", axis) for
+    Returns ("state", 0) for TAF detector-state leaves and the expert row
+    counter (per-shard, leading shard dim added by
+    `models.lm.shard_decode_state`), ("batch", axis) for
     leaves carrying the request-lane dim (KV cache, TAF memos), or None for
     replicated leaves. Same caveat as `cache_shardings`: the batch dim is
     found as the FIRST dim equal to `batch_size`, so engines should avoid
     slot counts that collide with the layer/head/sequence extents of their
     cache (e.g. slots == n_layers on a smoke config).
     """
-    from repro.models.lm import TAF_SHARD_STATE
+    from repro.models.lm import MOE_ROWS, TAF_SHARD_STATE
     parts = [str(p) for p in path]
     name = parts[-1].strip("[]'\" .") if parts else ""
     if any("taf" in p for p in parts) and name in TAF_SHARD_STATE:
+        return ("state", 0)
+    if name == MOE_ROWS:
         return ("state", 0)
     for i, s in enumerate(shape):
         if s == batch_size:

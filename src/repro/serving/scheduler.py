@@ -29,7 +29,7 @@ import contextlib
 import dataclasses
 import functools
 import time
-from typing import Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 import jax
@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro.core.types import ApproxSpec, Technique
 from repro.launch import steps as steps_mod
+from repro.models import lm as lm_mod
 from repro.models.lm import Model
 from repro.obs import recorder as obs_recorder
 from repro.obs import trace
@@ -95,6 +96,35 @@ class EngineStats:
     # per-request latency samples (seconds), appended as requests progress:
     ttft_s: List[float] = dataclasses.field(default_factory=list)
     latency_s: List[float] = dataclasses.field(default_factory=list)
+    # the expert row counts of a dropless expert stack, (routed per layer
+    # and held expert, computed) or None (`models.lm.moe_row_counts`); the
+    # moe_rows_* properties call it, so a tick never reads the device
+    moe_row_counts: Optional[Callable[[], Optional[Tuple]]] = \
+        dataclasses.field(default=None, repr=False, compare=False)
+
+    def _moe_rows(self) -> Optional[Tuple]:
+        return self.moe_row_counts() if self.moe_row_counts else None
+
+    @property
+    def moe_rows_routed(self) -> int:
+        """Rows routed to the held experts, every expert layer, every
+        decode step since the live cache was made."""
+        rows = self._moe_rows()
+        return int(rows[0].sum()) if rows else 0
+
+    @property
+    def moe_rows_computed(self) -> int:
+        """Expert rows the same decode steps' products computed (a masked
+        product computes every held expert on every lane)."""
+        rows = self._moe_rows()
+        return rows[1] if rows else 0
+
+    @property
+    def moe_rows_max(self) -> int:
+        """The largest count of rows routed to one held expert of one
+        layer."""
+        rows = self._moe_rows()
+        return int(rows[0].max()) if rows else 0
 
     @property
     def taf_skip_fraction(self) -> float:
@@ -155,7 +185,7 @@ class ServingEngine:
         self.active: List[Optional[Request]] = [None] * slots
         self.pos = np.zeros(slots, np.int64)       # next write position
         self.limit = np.zeros(slots, np.int64)     # stop position
-        self.stats = EngineStats()
+        self.stats = EngineStats(moe_row_counts=self._moe_row_counts)
         if devices is not None and mesh is None:
             from repro.runtime import elastic
             if devices > len(jax.devices()):
@@ -212,6 +242,7 @@ class ServingEngine:
             self._serve = jax.jit(steps_mod.make_serve_step(model),
                                   donate_argnums=(1,))
         self.cache = None
+        self._cache_steps = 0       # decode steps the live cache has had
         self.tokens = self._place_tokens(jnp.zeros((slots,), jnp.int32))
         self.qos = qos
         self._knob = None                    # last actuated threshold(s)
@@ -272,6 +303,11 @@ class ServingEngine:
                     "approxlint found serving misconfigurations: "
                     + "; ".join(f"{f.rule} {f.subject}: {f.message}"
                                 for f in findings))
+
+    def _moe_row_counts(self) -> Optional[Tuple]:
+        """The live cache's expert row counts, read off the device."""
+        return lm_mod.moe_row_counts(self.model.cfg, self.cache,
+                                     self._cache_steps, self.lanes_per_shard)
 
     @property
     def knob_log(self) -> List[tuple]:
@@ -397,15 +433,13 @@ class ServingEngine:
             tokens, NamedSharding(self.mesh, shardlib.batch_spec(self.mesh)))
 
     def _shard_cache(self, cache):
-        """Convert a freshly prefilled cache to the sharded TAF layout
-        (leading shard dim on the detector state) and commit it to the
-        mesh. No-op unsharded."""
+        """Convert a freshly prefilled cache to the sharded layout (leading
+        shard dim on the TAF detector state and the expert row counter)
+        and commit it to the mesh. No-op unsharded."""
         if self.mesh is None or cache is None:
             return cache
-        if "taf" in cache:
-            from repro.models.lm import shard_taf_state
-            cache = shard_taf_state(cache, self.n_shards)
-        return self._place_cache(cache)
+        return self._place_cache(lm_mod.shard_decode_state(cache,
+                                                           self.n_shards))
 
     def warmup(self):
         """Compile prefill, serve, and (QoS) the canary oracle on
@@ -481,6 +515,7 @@ class ServingEngine:
             logits, cache = self._prefill(self.params,
                                           {"tokens": jnp.asarray(prompts)})
             self.cache = self._shard_cache(cache)
+            self._cache_steps = 0
             self.tokens = self._place_tokens(
                 jnp.argmax(logits, axis=-1).astype(jnp.int32))
             self._knob = None   # fresh cache: actuate on the next plan
@@ -614,6 +649,7 @@ class ServingEngine:
                 with trace.span("tick.serve", live=len(live)):
                     self.tokens, logits, self.cache = self._serve(
                         self.params, self.cache, self.tokens, jnp.int32(pos))
+                    self._cache_steps += 1
                 if canary:
                     # Score ONLY the live lanes -- idle/retired slots hold
                     # zero-padded or stale state nobody consumes, and

@@ -70,7 +70,7 @@ class TestArchSmoke:
 @pytest.mark.parametrize("arch", ["deepseek-7b", "qwen3-1.7b",
                                   "starcoder2-3b", "qwen1.5-4b",
                                   "rwkv6-1.6b", "zamba2-7b",
-                                  "whisper-large-v3"])
+                                  "whisper-large-v3", "moonlight-16b-a3b"])
 def test_decode_matches_forward(arch):
     """Greedy decode with KV cache == teacher-forced forward (f32)."""
     cfg = dataclasses.replace(get_smoke_config(arch), remat=False,
@@ -176,7 +176,8 @@ def test_param_counts_match_targets():
                "olmoe-1b-7b": (6e9, 8e9),
                "pixtral-12b": (10e9, 14e9),
                "deepseek-7b": (6e9, 8e9),
-               "starcoder2-3b": (2.5e9, 4e9)}
+               "starcoder2-3b": (2.5e9, 4e9),
+               "moonlight-16b-a3b": (15.9e9, 16.1e9)}
     for arch, (lo, hi) in targets.items():
         n = get_config(arch).param_count()
         assert lo < n < hi, f"{arch}: {n/1e9:.1f}B outside [{lo},{hi}]"

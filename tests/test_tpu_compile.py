@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 import chip_smoke  # noqa: E402
+from repro.configs import get_config  # noqa: E402
 
 V5E_HBM_BYTES = 16 * 10 ** 9
 KERNEL_NAMES = [c[0] for c in chip_smoke.kernel_cases()]
@@ -179,3 +180,56 @@ def test_serve_step_writes_rows_into_the_donated_cache(
               and f[2] == "dynamic-update-slice"]
     assert len(writes) <= len(kv)
     assert [f for f in found if f not in writes] == []
+
+
+@pytest.fixture(scope="module")
+def moonlight(one_chip):
+    """Moonlight-16B-A3B as the benchmark serves it (8 of 64 experts held,
+    bf16), with its decode cache at the cell's 128 slots x 768 positions."""
+    from repro.models import build
+    cfg = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(cfg, remat=False, moe=dataclasses.replace(
+        cfg.moe, n_held=8))
+    model = build(cfg)
+    params = _shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    cache = _shapes(jax.eval_shape(lambda: model.init_cache(128, 768)),
+                    one_chip)
+    return model, params, cache
+
+
+def test_moonlight_decode_reads_the_latent_cache_in_place(
+        moonlight, one_chip, no_compile_cache):
+    """The donated serve step aliases the latent cache and holds no copy of
+    a whole layer's `ckv`, nor of a stacked one; the step fits one chip."""
+    from repro.launch import steps
+    model, params, cache = moonlight
+    tokens = jax.ShapeDtypeStruct((128,), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(steps.make_serve_step(model), donate_argnums=(1,)
+                       ).lower(params, cache, tokens, pos).compile()
+    _fits_one_chip(compiled)
+    latent = [cache[k][name] for k in ("dense", "moe")
+              for name in ("ckv", "k_rope")]
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in latent)
+    ckv = {cache[k]["ckv"].shape for k in ("dense", "moe")}
+    found = _cache_sized_buffers(compiled.as_text(),
+                                 ckv | {s[1:] for s in ckv})
+    writes = [f for f in found if f[0].startswith("ENTRY")
+              and f[2] == "dynamic-update-slice"]
+    assert len(writes) <= 2
+    assert [f for f in found if f not in writes] == []
+
+
+def test_moonlight_first_wave_prefill_fits_one_chip(
+        moonlight, one_chip, no_compile_cache):
+    """The engine's first admission prefills all 128 prompts of 256 tokens
+    at once (the grouped expert product)."""
+    from repro.launch import steps
+    model, params, _ = moonlight
+    prompts = jax.ShapeDtypeStruct((128, 256), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(steps.make_prefill_step(model, 768)).lower(
+        params, {"tokens": prompts}).compile()
+    _fits_one_chip(compiled)
+    assert "ragged" in compiled.as_text()
