@@ -229,21 +229,10 @@ class QosEngine:
 
     def observe_shard(self, shard: int, exact_logits, approx_logits,
                       lane_classes: Sequence[str]) -> float:
-        """Score one shard's slice of a canary tick. The error feeds three
-        places: the shared monitor (lifetime stats + the report estimate),
-        the per-class evidence monitors of the classes live on THIS shard
-        (each class judges its bound only against canaries measured under
-        a knob it was actually exposed to), and the shard's exposure
-        record (per-shard canary attribution in `summary()`)."""
-        if self._n_shards is None:
-            raise ValueError("call enable_sharding() before observe_shard()")
-        exact_q, approx_q = self._qoi(exact_logits, approx_logits)
-        err = self.monitor.observe(exact_q, approx_q)
-        for cls in sorted({self._norm_class(c) for c in lane_classes}):
-            self._exposure[cls].append(err)
-            self.class_monitors[cls].record(err)
-        self._shard_exposure[shard].append(err)
-        return err
+        """Score one shard's slice of a canary tick from its logits
+        (`observe_qoi` with `shard`)."""
+        return self.observe_qoi(*self.qoi(exact_logits, approx_logits),
+                                lane_classes, shard=shard)
 
     def update_shards(self,
                       shard_classes: Sequence[Sequence[str]]) -> None:
@@ -319,10 +308,19 @@ class QosEngine:
         """Advance the canary schedule (call exactly once per tick)."""
         return self.monitor.should_sample()
 
-    def _qoi(self, exact_logits, approx_logits):
-        """Metric-specific QoI: for "mape" the logits tensor; for "mcr"
-        the decoded token ids (argmax) -- the serving analogues of the
-        offline metrics' QoI choices."""
+    @property
+    def scores_tokens(self) -> bool:
+        """Whether the metric's QoI is the decoded token ids ("mcr"): a
+        serving loop then hands `observe_qoi` the two steps' next tokens
+        and copies no logits to the host. Under "mape" the QoI is the
+        logits themselves."""
+        return self.monitor.metric == "mcr"
+
+    def qoi(self, exact_logits, approx_logits):
+        """Metric-specific QoI of two logits tensors, as host arrays: for
+        "mape" the logits themselves; for "mcr" the decoded token ids
+        (argmax) -- the serving analogues of the offline metrics' QoI
+        choices."""
         if self.monitor.metric == "mcr":
             return (np.argmax(np.asarray(exact_logits), axis=-1),
                     np.argmax(np.asarray(approx_logits), axis=-1))
@@ -330,14 +328,35 @@ class QosEngine:
 
     def observe_decode(self, exact_logits, approx_logits,
                        lane_classes: Sequence[str] = ()) -> float:
-        """Score one canary tick (single-lane-group mode; sharded engines
-        use `observe_shard`). `lane_classes` (the live lanes' classes)
-        attributes the canary to every class exposed to this tick's
-        knob."""
-        exact_q, approx_q = self._qoi(exact_logits, approx_logits)
+        """Score one canary tick from its logits (`observe_qoi`)."""
+        return self.observe_qoi(*self.qoi(exact_logits, approx_logits),
+                                lane_classes)
+
+    def observe_qoi(self, exact_q, approx_q,
+                    lane_classes: Sequence[str] = (),
+                    shard: Optional[int] = None) -> float:
+        """Score one canary pair whose QoI is already extracted (the token
+        ids under "mcr", see `scores_tokens`; the logits under "mape").
+        `lane_classes` (the live lanes' classes) attributes the canary to
+        every class exposed to this tick's knob.
+
+        Without `shard` (single-lane-group mode) the error feeds the shared
+        monitor and the classes' exposure. With `shard` (sharded mode: one
+        shard's slice of the tick) it also feeds the evidence monitors of
+        the classes live on THIS shard (each class judges its bound only
+        against canaries measured under a knob it was actually exposed to)
+        and the shard's exposure record (per-shard canary attribution in
+        `summary()`)."""
+        if shard is not None and self._n_shards is None:
+            raise ValueError(
+                "call enable_sharding() before scoring a shard's canary")
         err = self.monitor.observe(exact_q, approx_q)
-        for cls in {self._norm_class(c) for c in lane_classes}:
+        for cls in sorted({self._norm_class(c) for c in lane_classes}):
             self._exposure[cls].append(err)
+            if shard is not None:
+                self.class_monitors[cls].record(err)
+        if shard is not None:
+            self._shard_exposure[shard].append(err)
         return err
 
     def update(self, lane_classes: Optional[Sequence[str]] = None) -> None:

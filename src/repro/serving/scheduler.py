@@ -18,7 +18,9 @@ their request class's current knob (`batching.group_lanes` via
 TAF threshold into the decode cache -- a TRACED value, so knob moves never
 recompile -- and, on canary ticks, runs the step through the precise model
 from the same pre-tick state, before the serve step consumes it, and feeds
-the compared logits to the quality monitor. A hard fallback zeroes both
+the compared QoI to the quality monitor: the two steps' token ids where
+it scores token ids ("mcr"), which copies only the exact step's tokens to
+the host, else both steps' logits. A hard fallback zeroes both
 the threshold and the in-flight prediction counters, so "precise" takes
 effect on the very next token.
 """
@@ -92,6 +94,7 @@ class EngineStats:
     taf_skipped: int = 0
     taf_total: int = 0
     canary_ticks: int = 0           # ticks re-executed through the oracle
+    canary_host_bytes: int = 0      # bytes the canary ticks copied to host
     knob_moves: int = 0             # actuator writes (QoS rung changes)
     # per-request latency samples (seconds), appended as requests progress:
     ttft_s: List[float] = dataclasses.field(default_factory=list)
@@ -585,12 +588,18 @@ class ServingEngine:
             trace.event("knob_move", tick=move.tick, value=move.value,
                         previous=move.previous, reason=move.reason)
 
-    def _score_canary(self, exact_logits, logits, live, lane_classes,
+    def _canary_host_bytes(self) -> int:
+        """Bytes one canary tick copies to the host: the exact step's
+        (slots,) int32 tokens where the monitor scores token ids, else the
+        exact and the approximate step's (slots, vocab) float32 logits."""
+        if self.qos.scores_tokens:
+            return self.n_slots * 4
+        return 2 * self.n_slots * self.model.cfg.padded_vocab_size * 4
+
+    def _score_canary(self, exact, approx, live, lane_classes,
                       shard_classes):
-        """Feed one canary tick's exact and approximate logits of the live
-        lanes to the quality monitor."""
-        ex = np.asarray(exact_logits)
-        ap = np.asarray(logits)
+        """Feed one canary tick's exact and approximate QoI of the live
+        lanes (host arrays, one row per lane) to the quality monitor."""
         if self.sharded:
             # per-shard attribution: each shard's slice is scored
             # separately, so a canary error is credited only to the shard
@@ -598,10 +607,10 @@ class ServingEngine:
             for s in range(self.n_shards):
                 lanes = [i for i in live if self._lane_shard(i) == s]
                 if lanes:
-                    self.qos.observe_shard(s, ex[lanes], ap[lanes],
-                                           shard_classes[s])
+                    self.qos.observe_qoi(exact[lanes], approx[lanes],
+                                         shard_classes[s], shard=s)
         else:
-            self.qos.observe_decode(ex[live], ap[live], lane_classes)
+            self.qos.observe_qoi(exact[live], approx[live], lane_classes)
 
     def tick(self) -> int:
         """One engine step: admit, decode one token for all active slots,
@@ -642,27 +651,38 @@ class ServingEngine:
                 if canary:
                     # canary: the precise oracle from the SAME pre-tick
                     # state, dispatched before the serve step consumes it;
-                    # the serve step's span nests inside this one
-                    in_canary.enter_context(trace.span("tick.canary"))
-                    _, exact_logits = self._serve_exact(
+                    # the serve step's and the read-back's spans nest
+                    # inside this one
+                    host_bytes = self._canary_host_bytes()
+                    in_canary.enter_context(
+                        trace.span("tick.canary", host_bytes=host_bytes))
+                    exact_tokens, exact_logits = self._serve_exact(
                         self.params, self.cache, self.tokens, jnp.int32(pos))
                 with trace.span("tick.serve", live=len(live)):
                     self.tokens, logits, self.cache = self._serve(
                         self.params, self.cache, self.tokens, jnp.int32(pos))
                     self._cache_steps += 1
+                with trace.span("tick.host_read"):
+                    toks = np.asarray(self.tokens)
+                    if self.cache is not None and "taf" in self.cache:
+                        rem = np.asarray(self.cache["taf"]["remaining"])
+                        self.stats.taf_skipped += int((rem > 0).sum())
+                        self.stats.taf_total += rem.size
                 if canary:
                     # Score ONLY the live lanes -- idle/retired slots hold
                     # zero-padded or stale state nobody consumes, and
-                    # their garbage logits would pollute the estimate.
-                    self._score_canary(exact_logits, logits, live,
-                                       lane_classes, shard_classes)
+                    # their garbage outputs would pollute the estimate.
+                    if self.qos.scores_tokens:
+                        # the QoI is the token ids: the approximate ones
+                        # are the tokens just read back, so only the exact
+                        # step's are copied
+                        qoi = np.asarray(exact_tokens), toks
+                    else:
+                        qoi = self.qos.qoi(exact_logits, logits)
+                    self._score_canary(*qoi, live, lane_classes,
+                                       shard_classes)
                     self.stats.canary_ticks += 1
-            with trace.span("tick.host_read"):
-                toks = np.asarray(self.tokens)
-                if self.cache is not None and "taf" in self.cache:
-                    rem = np.asarray(self.cache["taf"]["remaining"])
-                    self.stats.taf_skipped += int((rem > 0).sum())
-                    self.stats.taf_total += rem.size
+                    self.stats.canary_host_bytes += host_bytes
             now = time.time()
             with trace.span("tick.retire"):
                 for i in live:

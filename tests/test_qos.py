@@ -376,6 +376,41 @@ def test_qos_engine_observe_decode_metrics():
     assert err == 0.5
 
 
+@pytest.mark.parametrize("metric", ["mape", "mcr"])
+def test_qos_engine_observe_qoi_scores_as_observe_decode(metric):
+    """`observe_qoi` on the QoI the metric reads (token ids under "mcr",
+    the logits under "mape") books the same errors, in the same places, as
+    `observe_decode` / `observe_shard` on the logits."""
+    rng = np.random.RandomState(3)
+    pol = qos.QosPolicy(make_policy().entries, metric=metric)
+    targets = {"default": 0.5, "batch": 1.0}
+    for shards in (None, 2):
+        by_logits, by_qoi = (qos.QosEngine(pol, targets, sample_fraction=1.0)
+                             for _ in range(2))
+        assert by_qoi.scores_tokens == (metric == "mcr")
+        if shards:
+            for eng in (by_logits, by_qoi):
+                eng.enable_sharding(shards)
+        for i in range(6):
+            ex, ap = rng.randn(3, 5), rng.randn(3, 5)
+            classes = ["default", "batch"][:1 + i % 2]
+            shard = None if shards is None else i % shards
+            if shard is None:
+                err = by_logits.observe_decode(ex, ap, classes)
+            else:
+                err = by_logits.observe_shard(shard, ex, ap, classes)
+            if metric == "mcr":
+                ex, ap = np.argmax(ex, -1), np.argmax(ap, -1)
+            assert by_qoi.observe_qoi(ex, ap, classes, shard=shard) == err
+            assert err == {"mape": mape, "mcr": mcr}[metric](ex, ap)
+        assert by_qoi.summary() == by_logits.summary()
+        assert by_qoi._exposure == by_logits._exposure
+        for cls, mon in by_qoi.class_monitors.items():
+            assert mon.stats() == by_logits.class_monitors[cls].stats()
+    with pytest.raises(ValueError, match="enable_sharding"):
+        qos.QosEngine(pol, 0.5).observe_qoi(ex, ap, shard=0)
+
+
 # --------------------------------------------- closed loop through serving
 
 @pytest.fixture(scope="module")
@@ -512,54 +547,97 @@ def _undonated(eng):
     return eng
 
 
-def test_serving_donated_cache_keeps_tokens_and_canary_scores(decode_setup):
+class _LogitsQos(qos.QosEngine):
+    """A QoS engine whose serving loop scores every canary from both
+    steps' logits, whatever the metric."""
+    scores_tokens = False
+
+
+@pytest.mark.parametrize("metric", ["mape", "mcr"])
+def test_serving_donated_cache_keeps_tokens_and_canary_scores(decode_setup,
+                                                              metric):
     """The QoS engine with a donated cache serves the same tokens and the
-    same canary scores as without donation, and on every canary tick the
-    exact logits equal the precise step run on a saved copy of the
-    pre-tick cache and tokens."""
+    same canary scores as without donation. On every canary tick the
+    score equals the metric over the live lanes of the precise and the
+    approximate step run again on a saved copy of the pre-tick cache and
+    tokens. Under "mcr" the canary copies only the exact step's tokens to
+    the host, and an engine made to score from both steps' logits gives
+    the same scores, knob moves and tokens."""
     import jax.numpy as jnp
+    from repro.launch import steps
     from repro.serving import ServingEngine
     cfg, model, params = decode_setup
     runs = {}
-    for name in ("donated", "undonated"):
-        engine_qos = qos.QosEngine(
-            serving_policy(), 2.0, sample_fraction=0.5, window=4,
+    names = ("donated", "undonated") + (("logits",) if metric == "mcr"
+                                        else ())
+    for name in names:
+        engine_qos = (_LogitsQos if name == "logits" else qos.QosEngine)(
+            serving_policy(metric), 2.0, sample_fraction=1.0, window=4,
             config=ctl_config(min_samples=1, hold_ticks=1))
         eng = ServingEngine(model, params, slots=2, max_len=32,
                             prompt_len=8, qos=engine_qos)
         if name == "undonated":
             _undonated(eng)
         saved, canaries = [], []
-        serve, observe = eng._serve, engine_qos.observe_decode
+        serve, observe = eng._serve, engine_qos.observe_qoi
 
         def step(params, cache, tokens, pos, serve=serve, saved=saved):
             saved.append((jax.tree.map(jnp.copy, cache), jnp.copy(tokens),
                           pos))
             return serve(params, cache, tokens, pos)
 
-        def scored(ex, ap, classes, observe=observe, saved=saved,
-                   canaries=canaries):
-            canaries.append((saved[-1], np.asarray(ex),
-                             observe(ex, ap, classes)))
-            return canaries[-1][2]
+        def scored(ex, ap, classes, shard=None, observe=observe,
+                   saved=saved, canaries=canaries, eng=eng):
+            live = [i for i, r in enumerate(eng.active) if r is not None]
+            canaries.append((saved[-1], live, np.asarray(ex),
+                             observe(ex, ap, classes, shard=shard)))
+            return canaries[-1][3]
         eng._serve = step
-        engine_qos.observe_decode = scored
+        engine_qos.observe_qoi = scored
         reqs = _requests(cfg, 3, gen=10, cls="batch")
         for r in reqs:
             eng.submit(r)
+        for _ in range(4):
+            eng.tick()
+        engine_qos.monitor.inject(10.0)       # a fault: the knob falls back
         stats = eng.run_until_drained()
         assert stats.finished == 3
         runs[name] = ([r.output for r in reqs], canaries, stats, eng)
-    (out_d, can_d, stats_d, eng), (out_u, can_u, stats_u, _) = (
-        runs["donated"], runs["undonated"])
-    assert out_d == out_u
-    assert stats_d.taf_skipped == stats_u.taf_skipped > 0
-    assert len(can_d) == len(can_u) == stats_d.canary_ticks > 0
-    assert [c[2] for c in can_d] == [c[2] for c in can_u]
-    for (cache, tokens, pos), ex, _ in can_d:
-        _, exact = eng._serve_exact(eng.params, cache, tokens, pos)
-        live = np.asarray(exact)[:ex.shape[0]]
-        np.testing.assert_array_equal(ex, live)
+    out_d, can_d, stats_d, eng = runs["donated"]
+    assert stats_d.taf_skipped > 0
+    assert len(can_d) == stats_d.canary_ticks == stats_d.ticks
+    assert {m.reason for m in eng.knob_events} >= {"loosen", "fallback"}
+    for out, can, stats, other in runs.values():
+        assert out == out_d
+        assert stats.taf_skipped == stats_d.taf_skipped
+        assert [c[3] for c in can] == [c[3] for c in can_d]
+        assert other.knob_log == eng.knob_log
+        assert ([(p.index, p.event)
+                 for p in other.qos.controllers["default"].trajectory]
+                == [(p.index, p.event)
+                    for p in eng.qos.controllers["default"].trajectory])
+    plain = jax.jit(steps.make_serve_step(eng.model))
+    metric_fn = {"mape": mape, "mcr": mcr}[metric]
+    for (cache, tokens, pos), live, ex, err in can_d:
+        exact_tokens, exact = eng._serve_exact(eng.params, cache, tokens, pos)
+        _, approx, _ = plain(eng.params, cache, tokens, pos)
+        exact, approx = np.asarray(exact)[live], np.asarray(approx)[live]
+        if metric == "mcr":
+            np.testing.assert_array_equal(ex, np.asarray(exact_tokens)[live])
+            exact, approx = np.argmax(exact, -1), np.argmax(approx, -1)
+        else:
+            np.testing.assert_array_equal(ex, exact)
+        assert err == metric_fn(exact, approx)
+    logits_bytes = 2 * 2 * cfg.padded_vocab_size * 4
+    if metric == "mcr":
+        assert any(c[3] > 0 for c in can_d)
+        assert stats_d.canary_host_bytes == 4 * 2 * stats_d.canary_ticks
+        stats_l = runs["logits"][2]
+        assert stats_l.canary_host_bytes == (logits_bytes
+                                             * stats_l.canary_ticks)
+    else:
+        assert stats_d.canary_host_bytes == (logits_bytes
+                                             * stats_d.canary_ticks)
 
 
 @pytest.mark.parametrize("engine", ["precise", "qos"])

@@ -60,9 +60,10 @@ records = [
                      (0.3, 0.08, 2.0)]]
 policy = qos.QosPolicy.from_records(records, metric="mcr")
 
-def run(devices, shards, slots, *, seed=0, inject_at=None, inject_shard=None):
-    engine_qos = qos.QosEngine(policy, {"default": 0.10, "batch": 0.5},
-                               sample_fraction=0.5, window=8)
+def run(devices, shards, slots, *, seed=0, inject_at=None, inject_shard=None,
+        engine=qos.QosEngine):
+    engine_qos = engine(policy, {"default": 0.10, "batch": 0.5},
+                        sample_fraction=0.5, window=8)
     eng = ServingEngine(model, params, slots=slots, max_len=48,
                         prompt_len=8, qos=engine_qos, devices=devices,
                         shards=shards)
@@ -118,6 +119,36 @@ assert es.knob_log == ep.knob_log or (
 print("WRAP_OK")
 """)
         assert "WRAP_OK" in out
+
+
+class TestShardedTokenCanary:
+    def test_token_canaries_score_as_the_logits_path(self):
+        """Under "mcr" each shard's canary is scored from the two steps'
+        token ids: on the same seeded trace, with a drilled shard, every
+        shard's exposure errors, the class evidence, the knob log and the
+        tokens equal those of an engine made to score from both steps'
+        logits, and only the exact step's tokens reach the host."""
+        out = run_sub(_PREAMBLE + r"""
+class LogitsQos(qos.QosEngine):
+    scores_tokens = False
+
+et, rt = run(2, 2, 4, inject_at=3, inject_shard=1)
+el, rl = run(2, 2, 4, inject_at=3, inject_shard=1, engine=LogitsQos)
+assert [r.output for r in rt] == [r.output for r in rl], "decode outputs"
+exp_t, exp_l = et.qos._shard_exposure, el.qos._shard_exposure
+assert exp_t == exp_l, (exp_t, exp_l)
+assert exp_t[0] and exp_t[1]
+assert any(e > 0 for errs in exp_t.values() for e in errs)
+for cls, mon in et.qos.class_monitors.items():
+    assert list(mon._window) == list(el.qos.class_monitors[cls]._window)
+assert et.knob_log == el.knob_log and len(et.knob_log) > 1
+n = et.stats.canary_ticks
+assert n == el.stats.canary_ticks > 0
+assert et.stats.canary_host_bytes == 4 * 4 * n
+assert el.stats.canary_host_bytes == 2 * 4 * cfg.padded_vocab_size * 4 * n
+print("TOKENS_OK", n, sum(map(sum, exp_t.values())))
+""", n_devices=2)
+        assert "TOKENS_OK" in out
 
 
 class TestZeroRecompile:
